@@ -1,4 +1,4 @@
-"""Event-core shoot-out: vmapped ``lax.while_loop`` vs the fused Pallas
+"""Event-core shoot-out: vmapped ``lax.while_loop`` vs the Pallas
 event-loop kernel on the lanes the kernel targets — STREAM-scale
 SS/StaticSteal schedules (K ~ 6e4 chunks per instance), the slowest lanes
 of the batched engine.
@@ -10,8 +10,7 @@ of every makespan/LIB — the accuracy contract of
 ``results/bench_event_kernel.json`` with the platform recorded: on CPU the
 Pallas core runs in interpret mode (a correctness vehicle, not a speed
 claim — the default core stays ``while_loop`` there); on TPU the same call
-compiles via Mosaic and lifts the per-iteration dispatch XLA leaves on the
-table.
+runs the Mosaic-compiled kernel.
 
 ``--smoke`` is the CI gate: a reduced-K lane through both cores, asserting
 bit-equality of the batch results and the serving what-if path, and

@@ -16,10 +16,12 @@ invariant (completed + dead-lettered == admitted) by construction.
 
 **kill-resume** — launch the same faulty run in a child process journaling
 wave-granularity snapshots (``RunJournal``), SIGKILL it mid-run, resume
-from the surviving journal in-process, and require the resumed
-``FleetReport`` to be **bit-identical** (every summary field and every
-latency sample) to an uninterrupted run.  On the ``slow`` tier this is the
-issue-level >=1M-request crash-safety gate.
+from the surviving journal, and require the resumed ``FleetReport`` to be
+**bit-identical** (every summary field and every latency sample) to an
+uninterrupted run.  The uninterrupted run and the resumed run happen in
+child processes too, one at a time: the parent only reads their files, so
+it never holds the device while a child needs it.  On the ``slow`` tier
+this is the issue-level >=1M-request crash-safety gate.
 
 Everything is recorded to ``results/bench_faults.json``.
 """
@@ -158,13 +160,52 @@ def recovery_gate(n: int, seed: int = 0) -> dict:
 # gate 2: SIGKILL mid-run, resume from the journal, bit-equal report
 # ---------------------------------------------------------------------------
 
-def _child_main(journal_dir: str, n: int, seed: int) -> None:
-    """Child-process entry for the kill gate: run journaled until killed."""
+def _child_main(mode: str, journal_dir: str, out: str, n: int,
+                seed: int) -> None:
+    """Child-process entry for the kill gate.  ``ref`` runs uninterrupted,
+    ``kill`` runs journaled until it is killed, ``resume`` resumes from the
+    journal; ``ref`` and ``resume`` save their report to ``out``."""
+    import numpy as np
+
     from repro.serving import RunJournal
 
     trace = _trace(n, seed)
-    journal = RunJournal(journal_dir, every=JOURNAL_EVERY, keep=3)
-    _run(trace, _recovery(), journal=journal)
+    journal = (None if mode == "ref" else
+               RunJournal(journal_dir, every=JOURNAL_EVERY, keep=3))
+    s, rep = _run(trace, _recovery(), journal=journal,
+                  resume=mode == "resume", keep_latencies=True)
+    if mode != "kill":
+        np.savez(out, summary=json.dumps(s), latencies=rep.latencies)
+
+
+def _child(mode: str, journal_dir: str, out: str, n: int, seed: int):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(SRC) + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    return subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), "--_child", mode,
+         "--journal", journal_dir, "--out", out, "--n", str(n),
+         "--seed", str(seed)],
+        env=env, stdout=subprocess.DEVNULL, stderr=subprocess.PIPE)
+
+
+def _child_report(mode: str, journal_dir: str, out: str, n: int,
+                  seed: int, timeout: float = 1800.0):
+    """Run one child to its end and load the report it saved."""
+    import numpy as np
+
+    proc = _child(mode, journal_dir, out, n, seed)
+    try:
+        _, err = proc.communicate(timeout=timeout)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    if proc.returncode != 0:
+        raise RuntimeError(f"{mode} child failed (rc={proc.returncode}): "
+                           f"{err.decode(errors='replace')[-2000:]}")
+    with np.load(out) as z:
+        return json.loads(str(z["summary"])), z["latencies"]
 
 
 def _kill_child_mid_run(journal_dir: str, n: int, seed: int,
@@ -174,13 +215,7 @@ def _kill_child_mid_run(journal_dir: str, n: int, seed: int,
     cooperative shutdown).  Returns the number of surviving snapshots."""
     from repro.serving import RunJournal
 
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(SRC) + os.pathsep + \
-        env.get("PYTHONPATH", "")
-    proc = subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__), "--_child", journal_dir,
-         "--n", str(n), "--seed", str(seed)],
-        env=env, stdout=subprocess.DEVNULL, stderr=subprocess.PIPE)
+    proc = _child("kill", journal_dir, "", n, seed)
     journal = RunJournal(journal_dir, every=JOURNAL_EVERY, keep=3)
     deadline = time.time() + timeout
     try:
@@ -205,22 +240,26 @@ def _kill_child_mid_run(journal_dir: str, n: int, seed: int,
 
 
 def kill_resume_gate(n: int, seed: int = 0) -> dict:
+    """Every fleet run of this gate happens in a child process, one at a
+    time; this process only reads the journal and the saved reports, so it
+    must not hold the device itself (``smoke`` runs it first)."""
     import numpy as np
 
-    trace = _trace(n, seed)
-    ref_s, ref = _run(trace, _recovery(), keep_latencies=True)
+    from repro.serving import RunJournal
+
     with tempfile.TemporaryDirectory() as d:
         jdir = os.path.join(d, "journal")
+        ref_s, ref_lat = _child_report("ref", jdir, os.path.join(d, "ref.npz"),
+                                       n, seed)
         snapshots = _kill_child_mid_run(jdir, n, seed)
-        from repro.serving import RunJournal
         journal = RunJournal(jdir, every=JOURNAL_EVERY, keep=3)
         resumed_wave = journal.waves()[-1]
-        res_s, res = _run(trace, _recovery(), journal=journal, resume=True,
-                          keep_latencies=True)
+        res_s, res_lat = _child_report("resume", jdir,
+                                       os.path.join(d, "resume.npz"), n, seed)
     drop = ("wall_s",)
     a = {k: v for k, v in ref_s.items() if k not in drop}
     b = {k: v for k, v in res_s.items() if k not in drop}
-    lat_equal = bool(np.array_equal(ref.latencies, res.latencies))
+    lat_equal = bool(np.array_equal(ref_lat, res_lat))
     print(f"faults kill-resume n={n}: killed with {snapshots} snapshots, "
           f"resumed from wave {resumed_wave}/{ref_s['waves']}, "
           f"bit-equal={'yes' if a == b and lat_equal else 'NO'}", flush=True)
@@ -246,9 +285,11 @@ def smoke(tier: str = "tier1") -> None:
     tier)."""
     n = SMOKE_N.get(tier, SMOKE_N["tier1"])
     results = {"config": _config(n), "tier": tier}
-    results["recovery"] = recovery_gate(n)
-    _write(results)
+    # the kill gate's children need the device: run it before this process
+    # touches JAX in the recovery gate
     results["kill_resume"] = kill_resume_gate(n)
+    _write(results)
+    results["recovery"] = recovery_gate(n)
     _write(results)
 
 
@@ -278,12 +319,15 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--tier", default="tier1", choices=["tier1", "slow"])
-    ap.add_argument("--_child", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--_child", default=None,
+                    choices=["ref", "kill", "resume"], help=argparse.SUPPRESS)
+    ap.add_argument("--journal", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--out", default=None, help=argparse.SUPPRESS)
     ap.add_argument("--n", type=int, default=SMOKE_N["tier1"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     if args._child:
-        _child_main(args._child, args.n, args.seed)
+        _child_main(args._child, args.journal, args.out, args.n, args.seed)
     elif args.smoke:
         smoke(args.tier)
     else:

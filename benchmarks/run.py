@@ -14,7 +14,7 @@ Benches:
     roofline     §Roofline   three-term roofline per dry-run cell
     backends     §Backends   portfolio sweep: python vs batched JAX engine
     replay       §Backends   lockstep multi-cell replay vs sequential
-    event_kernel §Backends   while_loop vs fused Pallas event core
+    event_kernel §Backends   while_loop vs Pallas event core
     simpolicy    §SimAS      simulation-assisted selection regret + latency
     perturb      §Perturb    reactive re-pricing vs frozen under perturbations
     fleet        §Fleet      trace-driven routing over replica groups
@@ -25,7 +25,12 @@ Benches:
 ``--smoke`` is the single CI entry point: it runs every registered smoke
 gate for the requested tier and ALWAYS writes ``results/smoke_summary.json``
 (per-gate status, duration, error) before exiting non-zero on any failure —
-the summary is the triage artifact CI uploads with ``if: always()``.
+the summary is the triage artifact CI uploads with ``if: always()``.  Each
+gate runs in a child process of its own, one at a time, and the parent
+never touches JAX: on a TPU host a device belongs to one process, and a
+gate may start children that need it (``bench_faults``' kill gate).
+
+A bench whose input is missing fails; it is never reported as a skip.
 """
 
 from __future__ import annotations
@@ -34,11 +39,12 @@ import argparse
 import inspect
 import json
 import os
+import subprocess
 import sys
 import time
-import traceback
 
-RESULTS = os.path.join(os.path.dirname(__file__), "..", "results")
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+RESULTS = os.path.join(ROOT, "results")
 
 #: every CI smoke gate: name -> (module, tier | tuple-of-tiers).  tier1
 #: gates are fast drift checks run next to the unit tests; slow gates ride
@@ -61,14 +67,25 @@ SMOKE_GATES = {
 }
 
 
-def run_smoke(tier: str) -> int:
-    """Run every registered smoke gate for ``tier`` ("all" runs everything);
-    ``results/smoke_summary.json`` is rewritten after EVERY gate so a
-    killed process (OOM, job timeout) still leaves the partial record the
-    ``if: always()`` artifact upload exists for.  Returns the number of
-    failed gates."""
+def run_gate(name: str, tier: str) -> None:
+    """Run one registered smoke gate in this process (the child side of
+    :func:`run_smoke`); a failed gate raises."""
     import importlib
 
+    smoke_fn = importlib.import_module(
+        f"benchmarks.{SMOKE_GATES[name][0]}").smoke
+    if "tier" in inspect.signature(smoke_fn).parameters:
+        smoke_fn(tier=tier)          # tier-sized gates (e.g. fleet)
+    else:
+        smoke_fn()
+
+
+def run_smoke(tier: str) -> int:
+    """Run every registered smoke gate for ``tier`` ("all" runs everything),
+    each in a child process; ``results/smoke_summary.json`` is rewritten
+    after EVERY gate so a killed process (OOM, job timeout) still leaves the
+    partial record the ``if: always()`` artifact upload exists for.
+    Returns the number of failed gates."""
     summary = {"tier": tier, "gates": {}}
     os.makedirs(RESULTS, exist_ok=True)
     path = os.path.join(RESULTS, "smoke_summary.json")
@@ -78,7 +95,7 @@ def run_smoke(tier: str) -> int:
             json.dump(summary, f, indent=2)
 
     failures = 0
-    for name, (module, gate_tier) in SMOKE_GATES.items():
+    for name, (_, gate_tier) in SMOKE_GATES.items():
         tiers = (gate_tier,) if isinstance(gate_tier, str) else gate_tier
         rec = {"tier": "+".join(tiers)}
         run_tier = tier if tier != "all" else tiers[0]
@@ -91,18 +108,21 @@ def run_smoke(tier: str) -> int:
         summary["gates"][name] = rec
         flush_summary()
         t0 = time.perf_counter()
-        try:
-            smoke_fn = importlib.import_module(f"benchmarks.{module}").smoke
-            if "tier" in inspect.signature(smoke_fn).parameters:
-                smoke_fn(tier=run_tier)  # tier-sized gates (e.g. fleet)
-            else:
-                smoke_fn()
+        proc = subprocess.run(
+            [sys.executable, "-m", "benchmarks.run", "--_gate", name,
+             "--tier", run_tier],
+            cwd=ROOT, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+            text=True)
+        print(proc.stdout, end="", flush=True)
+        if proc.returncode == 0:
             rec["status"] = "ok"
-        except Exception as e:
+        else:
             failures += 1
+            err = proc.stderr.strip().splitlines() or [""]
             rec["status"] = "failed"
-            rec["error"] = f"{type(e).__name__}: {e}"
-            rec["traceback"] = traceback.format_exc(limit=8)
+            rec["error"] = err[-1]
+            rec["traceback"] = "\n".join(err[-40:])
+            print(proc.stderr, end="", file=sys.stderr, flush=True)
         rec["seconds"] = round(time.perf_counter() - t0, 3)
         flush_summary()
         print(f"smoke gate {name}: {rec['status']} "
@@ -121,10 +141,19 @@ def main() -> None:
     ap.add_argument("--tier", default="all",
                     choices=["tier1", "slow", "shard", "all"],
                     help="which smoke gates to run (with --smoke)")
+    ap.add_argument("--_gate", default=None, choices=sorted(SMOKE_GATES),
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
 
     if args.smoke:
         sys.exit(1 if run_smoke(args.tier) else 0)
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    if args._gate:
+        run_gate(args._gate, args.tier)
+        return
 
     from . import (bench_anova, bench_autotune, bench_backends, bench_chunks,
                    bench_cov, bench_degradation, bench_event_kernel,
@@ -163,8 +192,6 @@ def main() -> None:
                 print(f"{row[0]},{row[1]:.3f},{row[2]}")
             print(f"bench_{name}_wall,{(time.time() - t0) * 1e6:.0f},ok",
                   flush=True)
-        except FileNotFoundError as e:
-            print(f"bench_{name}_wall,0,SKIPPED({e})", flush=True)
         except Exception as e:
             failures += 1
             print(f"bench_{name}_wall,0,FAILED({type(e).__name__}: {e})",

@@ -18,6 +18,7 @@ top-k) — priced on ``--sim-backend``.
 import argparse
 
 from repro.core import resolve_sim_policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim import (APPLICATIONS, EXTENDED_SELECTOR_GRID, SELECTOR_GRID,
                        SIM_SELECTOR_GRID, SYSTEMS, run_campaign)
 
@@ -46,6 +47,7 @@ def main():
                     help="backend pricing the sim-assisted candidate sets "
                          "(default: --selector-backend)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.selectors is None:
         # resolve_sim_policy validates the env spelling (a typo raises)
         args.selectors = "sim" if resolve_sim_policy() else "paper"

@@ -1,39 +1,38 @@
-"""Fused OpenMP self-scheduling event loop — Pallas TPU kernel.
+"""OpenMP self-scheduling event loop — Pallas TPU kernel.
 
 The batched simulation backend's hot path is a sequential recurrence: for
 each dispatched chunk, assign it to the earliest-available PE (or to its
 pre-assigned owner for StaticSteal) and advance that PE's finish time.
-``lax.while_loop`` pays XLA per-iteration dispatch for every one of up to
-~1e5 chunks; this kernel runs the whole recurrence on-chip instead.
+``lax.while_loop`` runs one XLA loop iteration per chunk; this kernel runs
+the whole recurrence inside one kernel launch instead.
 
-Layout mirrors ``ssd_scan``: grid = (B, K // seg) with the chunk-segment
-axis innermost (sequential), so the per-PE finish times live in VMEM
-scratch and persist across segments — one kernel launch replaces K loop
-dispatches.  Segments past an instance's chunk count cost one guarded
-``fori_loop`` with zero trips.
+Layout is lanes-last, so every block is tile-aligned for Mosaic: the
+wrapper transposes the ``(B, K)`` chunk arrays to ``(K, B)`` and pads the
+lane axis B to a multiple of 128 with ``count == 0`` lanes.  The grid is
+``(B // 128, K // seg)`` with the chunk-segment axis innermost
+(sequential): each step streams a ``(seg, 128)`` block of effective costs
+and forced owners, and the ``(P, 128)`` per-PE finish times of 128 lanes
+live in VMEM scratch across segments.  One chunk step runs for all 128
+lanes at once:
 
-Two entry points share the assignment recurrence:
+* argmin over the PE (sublane) axis as the minimum followed by the first
+  PE index holding it — the same tie rule as ``jnp.argmin``;
+* the update as a one-hot masked add in place of a dynamic scatter; a lane
+  whose chunks are exhausted is masked and keeps its finish times.
 
-* ``event_finish`` — minimal sequential core ``(eff_costs, forced, count)
-  -> finish``: effective per-chunk costs are precomputed outside (the
-  serving what-if path, whose costs come from an exact float64 host
-  prefix-gather).
-* ``event_finish_fused`` — full fusion for the campaign path: the
-  prefix-grid cost gather (linear interpolation over the profile's
-  cumulative-cost row) and the locality/noise application also run
-  on-chip per segment, so the (B, K) effective-cost array is never
-  materialized to HBM.
+The trip count of a segment is the largest chunk count among its 128 lanes
+(scalar-prefetched per lane block), rounded up to the 8-row sublane tile;
+segments past every lane's count cost one skipped ``pl.when``.
 
-Accuracy contract (``tests/test_event_kernel.py``): both entry points are
+Accuracy contract (``tests/test_event_kernel.py``): the kernel is
 **bit-identical in interpret mode** to the vmapped ``lax.while_loop``
-reference core in ``repro.sim.backends.jax_batched`` — per chunk the op
-sequence ``fin[pe] += h_eff + eff[i] * speed[pe] + bcost`` (argmin ties to
-the lowest PE index) is replicated exactly, and all random draws
-(jitter/speed/noise) stay in the shared data-parallel precompute so every
-core sees the same noise realization.  Like every kernel module here, the
-entry points take an explicit ``interpret`` flag; the platform policy
-(interpret on CPU, Mosaic-compiled on TPU) lives in ``kernels/ops.py``,
-which the simulation backend routes through.
+reference core in ``repro.sim.backends.jax_batched`` — per chunk and lane
+the op sequence ``fin[pe] += h_eff + eff[i] * speed[pe] + bcost`` (argmin
+ties to the lowest PE index) is replicated exactly.  Effective costs,
+including the prefix-grid gather and every random draw, come from the
+backend's shared XLA precompute, so both cores see the same inputs.  The
+entry point takes an explicit ``interpret`` flag; the platform policy
+(interpret on CPU, Mosaic-compiled on TPU) lives in ``kernels/ops.py``.
 """
 
 from __future__ import annotations
@@ -48,71 +47,47 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: default chunk-segment length; divides every K bucket the backend pads to
 DEFAULT_SEG = 512
+#: lanes per kernel block (the vreg lane width)
+LANES = 128
+#: chunk rows per unrolled group (the f32 sublane tile)
+ROWS = 8
 
 
-def _assign_segment(eff, forced, speed, h_eff, bc, n, fin):
-    """Run ``n`` assignment steps of one segment (argmin / forced owner)."""
-
-    def body(i, fin):
-        pe = jnp.where(forced[i] >= 0, forced[i], jnp.argmin(fin))
-        return fin.at[pe].add(h_eff + eff[i] * speed[pe] + bc)
-
-    return lax.fori_loop(0, n, body, fin)
-
-
-def _loop_kernel(eff_ref, speed_ref, jit_ref, forced_ref, cnt_ref, sc_ref,
-                 out_ref, fin_scr, *, seg: int, n_seg: int):
-    si = pl.program_id(1)
+def _loop_kernel(nmax_ref, eff_ref, forced_ref, speed_ref, jit_ref, hb_ref,
+                 cnt_ref, out_ref, fin_scr, *, seg: int, n_seg: int):
+    bi, si = pl.program_id(0), pl.program_id(1)
 
     @pl.when(si == 0)
     def _init():
         fin_scr[...] = jit_ref[...]
 
-    n = jnp.clip(cnt_ref[0, 0] - si * seg, 0, seg)
+    n = jnp.clip(nmax_ref[bi] - si * seg, 0, seg)
 
-    @pl.when(n > 0)     # segments past the chunk count touch nothing
+    @pl.when(n > 0)     # segments past every lane's chunk count touch nothing
     def _run():
-        fin_scr[0] = _assign_segment(eff_ref[0], forced_ref[0], speed_ref[0],
-                                     sc_ref[0, 0], sc_ref[0, 1], n,
-                                     fin_scr[0])
+        P = fin_scr.shape[0]
+        pe_id = lax.broadcasted_iota(jnp.int32, fin_scr.shape, 0)
+        speed = speed_ref[...]
+        h_eff, bc = hb_ref[0:1, :], hb_ref[1:2, :]
+        left = cnt_ref[...] - si * seg      # (1, LANES) chunks still owed
 
-    @pl.when(si == n_seg - 1)
-    def _emit():
-        out_ref[...] = fin_scr[...]
+        def group(g, fin):
+            base = pl.multiple_of(g * ROWS, ROWS)
+            eff = eff_ref[pl.ds(base, ROWS), :]
+            forced = forced_ref[pl.ds(base, ROWS), :]
+            for r in range(ROWS):
+                low = jnp.min(fin, axis=0, keepdims=True)
+                first = jnp.min(jnp.where(fin == low, pe_id, P), axis=0,
+                                keepdims=True)
+                f = forced[r:r + 1, :]
+                pe = jnp.where(f >= 0, f, first)
+                hit = (pe_id == pe) & (base + r < left)
+                fin = jnp.where(hit, fin + (h_eff + eff[r:r + 1, :] * speed
+                                            + bc), fin)
+            return fin
 
-
-def _fused_kernel(gid_ref, row_ref, starts_ref, sizes_ref, loc_ref,
-                  noise_ref, speed_ref, jit_ref, forced_ref, cnt_ref, sc_ref,
-                  out_ref, fin_scr, *, seg: int, n_seg: int, G: int):
-    si = pl.program_id(1)
-
-    @pl.when(si == 0)
-    def _init():
-        fin_scr[...] = jit_ref[...]
-
-    n = jnp.clip(cnt_ref[0, 0] - si * seg, 0, seg)
-
-    @pl.when(n > 0)     # segments past the chunk count touch nothing
-    def _run():
-        # on-chip prefix-grid gather: costs of this segment's chunks via
-        # linear interpolation over the profile's cumulative-cost row —
-        # selected straight out of the deduplicated stack by the
-        # scalar-prefetched grid_id (no host-side (B, G+1) row gather)
-        row = row_ref[0]
-        gscale = sc_ref[0, 2]
-
-        def pref(x):
-            pos = x.astype(jnp.float32) * gscale
-            i = jnp.clip(pos.astype(jnp.int32), 0, G - 1)
-            lo = row[i]
-            return lo + (pos - i) * (row[i + 1] - lo)
-
-        starts = starts_ref[0]
-        costs = pref(starts + sizes_ref[0]) - pref(starts)
-        eff = costs * loc_ref[0] * noise_ref[0]
-        fin_scr[0] = _assign_segment(eff, forced_ref[0], speed_ref[0],
-                                     sc_ref[0, 0], sc_ref[0, 1], n,
-                                     fin_scr[0])
+        fin_scr[...] = lax.fori_loop(0, (n + ROWS - 1) // ROWS, group,
+                                     fin_scr[...])
 
     @pl.when(si == n_seg - 1)
     def _emit():
@@ -121,17 +96,10 @@ def _fused_kernel(gid_ref, row_ref, starts_ref, sizes_ref, loc_ref,
 
 def _seg_for(K: int, seg: int) -> int:
     seg = min(seg, K)
-    if K % seg:
-        raise ValueError(f"segment {seg} must divide padded length {K}")
+    if K % seg or seg % ROWS:
+        raise ValueError(f"segment {seg} must divide padded length {K} "
+                         f"and be a multiple of {ROWS}")
     return seg
-
-
-def _lane_specs(seg, P):
-    """BlockSpecs shared by both kernels: per-lane (1, seg) chunk segments,
-    (1, P) PE rows, and SMEM scalar rows."""
-    chunk = pl.BlockSpec((1, seg), lambda bi, si: (bi, si))
-    lane = pl.BlockSpec((1, P), lambda bi, si: (bi, 0))
-    return chunk, lane
 
 
 @functools.partial(jax.jit, static_argnames=("seg", "interpret"))
@@ -147,75 +115,33 @@ def event_finish(eff, speed, jitter, h_eff, bcost, forced, count, *,
     P = speed.shape[1]
     seg = _seg_for(K, seg)
     n_seg = K // seg
-    chunk, lane = _lane_specs(seg, P)
+    Bp = -(-B // LANES) * LANES
+    pad = ((0, Bp - B), (0, 0))
+    lanes_last = lambda x, v=0: jnp.pad(x, pad, constant_values=v).T
+    count = jnp.pad(count.astype(jnp.int32), (0, Bp - B))
+    nmax = count.reshape(Bp // LANES, LANES).max(axis=1)
+    chunk = pl.BlockSpec((seg, LANES), lambda bi, si, nmax: (si, bi))
+    lane = lambda rows: pl.BlockSpec((rows, LANES),
+                                     lambda bi, si, nmax: (0, bi))
     kernel = functools.partial(_loop_kernel, seg=seg, n_seg=n_seg)
-    return pl.pallas_call(
+    fin = pl.pallas_call(
         kernel,
-        grid=(B, n_seg),
-        in_specs=[
-            chunk,                                              # eff
-            lane,                                               # speed
-            lane,                                               # jitter
-            chunk,                                              # forced
-            pl.BlockSpec((1, 1), lambda bi, si: (bi, 0),
-                         memory_space=pltpu.SMEM),              # count
-            pl.BlockSpec((1, 2), lambda bi, si: (bi, 0),
-                         memory_space=pltpu.SMEM),              # h_eff, bcost
-        ],
-        out_specs=lane,
-        out_shape=jax.ShapeDtypeStruct((B, P), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, P), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                          # per-block nmax
+            grid=(Bp // LANES, n_seg),
+            in_specs=[chunk,                                # eff
+                      chunk,                                # forced
+                      lane(P),                              # speed
+                      lane(P),                              # jitter
+                      lane(2),                              # h_eff, bcost
+                      lane(1)],                             # count
+            out_specs=lane(P),
+            scratch_shapes=[pltpu.VMEM((P, LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((P, Bp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(eff, speed, jitter, forced, count.reshape(B, 1),
-      jnp.stack([h_eff, bcost], axis=1))
-
-
-@functools.partial(jax.jit, static_argnames=("seg", "interpret"))
-def event_finish_fused(grids, grid_id, gscale, starts, sizes, loc, noise,
-                       speed, jitter, h_eff, bcost, forced, count, *,
-                       seg: int = DEFAULT_SEG, interpret: bool = False):
-    """Fully fused campaign core: prefix-grid gather + locality/noise
-    application + assignment recurrence in one on-chip pass.
-
-    grids (S, G+1) f32 deduplicated cumulative-cost stack, grid_id (B,) i32
-    per-lane row index (scalar-prefetched: each lane's row streams straight
-    from the shared stack, never materializing a (B, G+1) gather), gscale
-    (B,) f32 (= G / N per lane), starts/sizes (B, K) i32, loc/noise (B, K)
-    f32; the rest as in :func:`event_finish`.  Returns finish (B, P) f32.
-    """
-    B, K = starts.shape
-    P = speed.shape[1]
-    G = grids.shape[1] - 1
-    seg = _seg_for(K, seg)
-    n_seg = K // seg
-    chunk = pl.BlockSpec((1, seg), lambda bi, si, gid_ref: (bi, si))
-    lane = pl.BlockSpec((1, P), lambda bi, si, gid_ref: (bi, 0))
-    kernel = functools.partial(_fused_kernel, seg=seg, n_seg=n_seg, G=G)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                                  # grid_id
-        grid=(B, n_seg),
-        in_specs=[
-            pl.BlockSpec((1, G + 1),
-                         lambda bi, si, gid_ref: (gid_ref[bi], 0)),  # row
-            chunk,                                              # starts
-            chunk,                                              # sizes
-            chunk,                                              # loc
-            chunk,                                              # noise
-            lane,                                               # speed
-            lane,                                               # jitter
-            chunk,                                              # forced
-            pl.BlockSpec((1, 1), lambda bi, si, gid_ref: (bi, 0),
-                         memory_space=pltpu.SMEM),              # count
-            pl.BlockSpec((1, 3), lambda bi, si, gid_ref: (bi, 0),
-                         memory_space=pltpu.SMEM),       # h_eff, bcost, gscale
-        ],
-        out_specs=lane,
-        scratch_shapes=[pltpu.VMEM((1, P), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, P), jnp.float32),
-        interpret=interpret,
-    )(grid_id, grids, starts, sizes, loc, noise, speed, jitter, forced,
-      count.reshape(B, 1), jnp.stack([h_eff, bcost, gscale], axis=1))
+    )(nmax, lanes_last(eff), lanes_last(forced, -1), lanes_last(speed),
+      lanes_last(jitter), lanes_last(jnp.stack([h_eff, bcost], axis=1)),
+      count.reshape(1, Bp))
+    return fin.T[:B]

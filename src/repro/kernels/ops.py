@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python with real block indexing, which is what the per-kernel
-allclose tests validate.  On TPU backends the same calls compile via Mosaic.
+On CPU the kernels execute in interpret mode — the kernel body runs in
+Python with real block indexing, which is what the per-kernel allclose tests
+validate.  On TPU backends the same calls compile via Mosaic
+(``tests/test_chip_compile.py`` compiles the event kernel for a v5e).
 """
 
 from __future__ import annotations
@@ -10,7 +11,6 @@ from __future__ import annotations
 import jax
 
 from .event_loop import event_finish as _event_finish
-from .event_loop import event_finish_fused as _event_finish_fused
 from .flash_attention import flash_attention as _flash
 from .rmsnorm import rmsnorm as _rmsnorm
 from .ssd_scan import ssd_scan as _ssd
@@ -23,12 +23,11 @@ def _interpret() -> bool:
 def preferred_event_core() -> str:
     """Platform default for the batched engine's sequential event core.
 
-    On accelerators the Pallas kernels compile (Mosaic on TPU) and the fused
-    event loop lifts the per-iteration dispatch XLA leaves on the table; on
-    CPU they only *interpret* (a correctness vehicle, 0.2–1.1x of the
-    while-loop core per ``results/bench_event_kernel.json``), so the vmapped
-    ``lax.while_loop`` reference stays the default there.  Kept here so the
-    interpret-vs-compile platform policy lives in one module.
+    On TPU the Pallas event kernel compiles via Mosaic and is the default;
+    whether it beats the ``lax.while_loop`` core there is not measured yet.
+    On CPU the kernel only *interprets* (a correctness vehicle), so the
+    vmapped ``lax.while_loop`` reference stays the default there.  Kept here
+    so the interpret-vs-compile platform policy lives in one module.
     """
     return "while_loop" if _interpret() else "pallas"
 
@@ -54,10 +53,3 @@ def event_finish(eff, speed, jitter, h_eff, bcost, forced, count, *,
     return _event_finish(eff, speed, jitter, h_eff, bcost, forced, count,
                          seg=seg, interpret=_interpret())
 
-
-def event_finish_fused(grids, grid_id, gscale, starts, sizes, loc, noise,
-                       speed, jitter, h_eff, bcost, forced, count, *,
-                       seg: int = 512):
-    return _event_finish_fused(grids, grid_id, gscale, starts, sizes, loc,
-                               noise, speed, jitter, h_eff, bcost, forced,
-                               count, seg=seg, interpret=_interpret())

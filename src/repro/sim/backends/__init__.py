@@ -3,7 +3,7 @@
 >>> from repro.sim.backends import get_backend
 >>> get_backend("python")      # reference event-loop engine
 >>> get_backend("jax")         # batched vmapped engine (campaign sweeps)
->>> get_backend("jax-pallas")  # same engine, fused Pallas event core
+>>> get_backend("jax-pallas")  # same engine, Pallas event core
 
 ``get_backend(None)`` resolves the default from the ``REPRO_SIM_BACKEND``
 environment variable (falling back to ``python``), so scripts and
@@ -12,10 +12,10 @@ every call site.  Backends are process-wide singletons — the JAX backend's
 schedule caches persist across sweeps.
 
 The JAX engine's sequential event core is itself pluggable
-(``REPRO_EVENT_CORE`` / ``JaxBatchedBackend(kernel=...)``): ``jax`` keeps
-the vmapped ``lax.while_loop`` reference, ``jax-pallas`` is the same
-backend constructed with the fused on-chip Pallas kernel
-(``repro.kernels.event_loop``).
+(``REPRO_EVENT_CORE`` / ``JaxBatchedBackend(kernel=...)``): ``jax`` takes
+the platform default (the compiled Pallas kernel on TPU, the vmapped
+``lax.while_loop`` reference on CPU), ``jax-pallas`` is the same backend
+constructed with the Pallas kernel (``repro.kernels.event_loop``).
 """
 
 from __future__ import annotations

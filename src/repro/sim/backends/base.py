@@ -9,7 +9,7 @@ so engines are interchangeable: the reference Python event loop
 (``backends.jax_batched``) must agree noise-free (``tests/test_backends.py``).
 The JAX engine additionally keeps its *sequential event core* pluggable
 behind a ``(eff_costs, forced, count) -> finish`` contract — a vmapped
-``lax.while_loop`` reference and a fused Pallas kernel that must match it
+``lax.while_loop`` reference and a Pallas kernel that must match it
 bit-for-bit (``tests/test_event_kernel.py``).
 
 ``EVENT_CAP`` is the *shared* event budget: both backends switch SS /

@@ -19,14 +19,13 @@ rep x time-step) — at once:
     ``(eff_costs, forced, count) -> finish`` with two interchangeable
     implementations: the vmapped ``lax.while_loop`` reference (argmin
     assignment, exactly the reference heap policy: one entry per PE, ties
-    to the lowest index) and the fused on-chip Pallas kernel
+    to the lowest index) and the Pallas kernel
     (``repro.kernels.event_loop``), selected via the ``kernel=``
     constructor argument / the ``REPRO_EVENT_CORE`` env var.  The Pallas
     core is bit-identical to the while-loop core in interpret mode
-    (``tests/test_event_kernel.py``) and additionally fuses the prefix
-    gather + locality/noise application on-chip for the campaign path.
-    ``run_batch``, ``run_lockstep`` and ``what_if_wave`` all route through
-    the selected core.
+    (``tests/test_event_kernel.py``).  ``run_batch``, ``run_lockstep``,
+    ``what_if_wave`` and ``what_if_routes`` all route through the selected
+    core.
 
 4.  On a multi-device host every batched lane dimension — ``run_batch`` /
     ``run_lockstep`` instances and the serving what-if candidate rows —
@@ -64,7 +63,6 @@ from __future__ import annotations
 
 import functools
 import os
-import warnings
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -72,7 +70,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as Pspec
 
 from ...core.jaxsched import (chunk_schedule, staticsteal_schedule,
@@ -123,34 +120,19 @@ def _pow2_rows(n: int) -> int:
     return b
 
 
-def _pallas_available() -> bool:
-    try:
-        from ...kernels import ops  # noqa: F401  (the routed kernel path)
-        return True
-    except Exception:       # pragma: no cover - exotic builds without pallas
-        return False
-
-
 def resolve_event_core(kernel: Optional[str] = None) -> str:
     """Resolve the sequential event core: explicit ``kernel=`` argument,
     else ``REPRO_EVENT_CORE``, else the platform default (``"auto"``):
-    the Mosaic-compiled Pallas kernel on accelerator platforms, the vmapped
+    the Mosaic-compiled Pallas kernel on TPU, the vmapped
     ``lax.while_loop`` reference on CPU, where Pallas only interprets (the
-    policy lives in ``kernels.ops.preferred_event_core``).  Falls back
-    (with a warning) when Pallas is unavailable in this jax build."""
+    policy lives in ``kernels.ops.preferred_event_core``)."""
     name = (kernel or os.environ.get(EVENT_CORE_ENV) or "auto").lower()
     if name == "auto":
-        if not _pallas_available():     # pragma: no cover - exotic builds
-            return "while_loop"
         from ...kernels.ops import preferred_event_core
         return preferred_event_core()
     if name not in EVENT_CORES:
         raise ValueError(f"unknown event core {name!r}; "
                          f"available: ['auto', *{list(EVENT_CORES)}]")
-    if name == "pallas" and not _pallas_available():
-        warnings.warn("Pallas unavailable; falling back to the "
-                      "while_loop event core", RuntimeWarning)
-        name = "while_loop"
     return name
 
 
@@ -272,29 +254,18 @@ def _batched_events_impl(P: int, core: str, grids, grid_id, inv_n, starts,
     # perturbation / heterogeneity enters HERE, in the shared precompute —
     # upstream of every event core, so while_loop and Pallas stay identical
     speed = speed * pe_mult
-    gscale = G * inv_n
 
-    if core == "pallas":
-        # full fusion: the prefix gather + locality/noise application run
-        # inside the kernel (rows scalar-prefetched per lane from the
-        # shared stack); eff never materializes to HBM
-        from ...kernels.ops import event_finish_fused
-        fin = event_finish_fused(grids, grid_id, gscale, starts, sizes, loc,
-                                 noise, speed, jitter, h_eff, bcost, forced,
-                                 count)
-    else:
-        def eff_one(gid, gs, starts, sizes, loc, noise):
-            def pref(x):
-                pos = x.astype(jnp.float32) * gs
-                i = jnp.clip(pos.astype(jnp.int32), 0, G - 1)
-                lo = grids[gid, i]
-                return lo + (pos - i) * (grids[gid, i + 1] - lo)
+    def eff_one(gid, gs, starts, sizes, loc, noise):
+        def pref(x):
+            pos = x.astype(jnp.float32) * gs
+            i = jnp.clip(pos.astype(jnp.int32), 0, G - 1)
+            lo = grids[gid, i]
+            return lo + (pos - i) * (grids[gid, i + 1] - lo)
 
-            return (pref(starts + sizes) - pref(starts)) * loc * noise
+        return (pref(starts + sizes) - pref(starts)) * loc * noise
 
-        eff = jax.vmap(eff_one)(grid_id, gscale, starts, sizes, loc, noise)
-        fin = _core_while(eff, speed, jitter, h_eff, bcost, forced, count)
-
+    eff = jax.vmap(eff_one)(grid_id, G * inv_n, starts, sizes, loc, noise)
+    fin = _core_finish(core, eff, speed, jitter, h_eff, bcost, forced, count)
     mk = fin.max(axis=1)
     lib = jnp.where(mk > 0.0, (1.0 - fin.mean(axis=1) / mk) * 100.0, 0.0)
     return mk, lib, fin
@@ -359,31 +330,31 @@ _route_eval = jax.jit(_route_eval_impl, static_argnums=(0, 1))
 @functools.lru_cache(maxsize=32)
 def _sharded_events(mesh, P: int, core: str):
     lane, rep = lane_spec(mesh), Pspec()
-    fn = shard_map(functools.partial(_batched_events_impl, P, core),
-                   mesh=mesh,
-                   in_specs=(rep,) + (lane,) * 12 + (rep,) * 3,
-                   out_specs=(lane, lane, lane),
-                   check_rep=False)   # no replicated outputs, no collectives
+    fn = jax.shard_map(functools.partial(_batched_events_impl, P, core),
+                       mesh=mesh,
+                       in_specs=(rep,) + (lane,) * 12 + (rep,) * 3,
+                       out_specs=(lane, lane, lane),
+                       check_vma=False)   # no replicated outputs/collectives
     return jax.jit(fn)
 
 
 @functools.lru_cache(maxsize=32)
 def _sharded_wave(mesh, R: int, core: str):
     lane, rep = lane_spec(mesh), Pspec()
-    fn = shard_map(functools.partial(_wave_eval_impl, R, core),
-                   mesh=mesh,
-                   in_specs=(lane, lane, lane, rep, rep),
-                   out_specs=lane, check_rep=False)
+    fn = jax.shard_map(functools.partial(_wave_eval_impl, R, core),
+                       mesh=mesh,
+                       in_specs=(lane, lane, lane, rep, rep),
+                       out_specs=lane, check_vma=False)
     return jax.jit(fn)
 
 
 @functools.lru_cache(maxsize=32)
 def _sharded_route(mesh, R: int, core: str):
     lane, rep = lane_spec(mesh), Pspec()
-    fn = shard_map(functools.partial(_route_eval_impl, R, core),
-                   mesh=mesh,
-                   in_specs=(lane, lane, lane, lane, rep),
-                   out_specs=lane, check_rep=False)
+    fn = jax.shard_map(functools.partial(_route_eval_impl, R, core),
+                       mesh=mesh,
+                       in_specs=(lane, lane, lane, lane, rep),
+                       out_specs=lane, check_vma=False)
     return jax.jit(fn)
 
 

@@ -3,14 +3,16 @@ reference core (interpret mode), across every portfolio algorithm, forced-PE
 StaticSteal rows, over-bucket schedule lengths, and random ragged batches.
 
 The contract under test (``repro.kernels.event_loop``): with identical
-inputs — the random draws live in the shared data-parallel precompute — the
-fused on-chip kernel must reproduce the reference core *bit for bit*, so
-switching ``REPRO_EVENT_CORE`` can never change a campaign statistic.
+inputs — the random draws and the prefix-grid gather live in the shared
+data-parallel precompute — the lanes-last kernel must reproduce the
+reference core *bit for bit*, so switching ``REPRO_EVENT_CORE`` can never
+change a campaign statistic.
 """
 
 import dataclasses
 import os
 import sys
+import warnings
 
 import numpy as np
 import pytest
@@ -48,7 +50,9 @@ UNIFORM = LoopProfile(name="u", N=4096, memory_bound=0.2, locality_sens=0.4,
 def test_resolve_event_core(monkeypatch):
     monkeypatch.delenv("REPRO_EVENT_CORE", raising=False)
     assert resolve_event_core() == "while_loop"
-    assert resolve_event_core("pallas") == "pallas"
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")     # no silent fallback to while_loop
+        assert resolve_event_core("pallas") == "pallas"
     monkeypatch.setenv("REPRO_EVENT_CORE", "pallas")
     assert resolve_event_core() == "pallas"
     assert resolve_event_core("while_loop") == "while_loop"   # arg wins
@@ -171,6 +175,51 @@ def test_random_ragged_schedules_property(seed, P, seg):
     np.testing.assert_array_equal(np.asarray(kernel), np.asarray(ref))
 
 
+@pytest.mark.parametrize("B,P,K,seg", [
+    (130, 8, 64, 16),      # B not a multiple of 128: two lane blocks
+    (3, 128, 256, 64),     # the widest paper machine (epyc)
+    (129, 20, 128, 128),   # one lane past a block, one segment
+    (1, 1, 8, 8),          # smallest what-if bucket, a single PE
+])
+def test_lanes_last_layout_edges(B, P, K, seg):
+    """Edges of the lanes-last layout: lane padding to 128, P=128, a lane
+    with count 0, a full lane, forced-PE rows on several lanes and ragged
+    counts — kernel == while-loop reference, bit for bit."""
+    from repro.kernels.event_loop import event_finish
+
+    rng = np.random.default_rng(B * 1000 + P)
+    eff = jnp.asarray(rng.random((B, K)), jnp.float32)
+    speed = jnp.asarray(1.0 + 0.2 * rng.standard_normal((B, P)), jnp.float32)
+    jitter = jnp.asarray(rng.random((B, P)) * 1e-2, jnp.float32)
+    h_eff = jnp.asarray(rng.random(B) * 1e-3, jnp.float32)
+    bcost = jnp.asarray(rng.random(B) * 1e-3, jnp.float32)
+    forced = np.full((B, K), -1, np.int32)
+    for lane in range(0, B, 3):
+        nf = int(rng.integers(0, K + 1))
+        forced[lane, :nf] = rng.integers(0, P, nf)
+    cnt = rng.integers(0, K + 1, B).astype(np.int32)
+    cnt[0] = K
+    cnt[-1] = 0
+    args = (eff, speed, jitter, h_eff, bcost, jnp.asarray(forced),
+            jnp.asarray(cnt))
+    kernel = np.asarray(event_finish(*args, seg=seg, interpret=True))
+    ref = np.asarray(_core_while(*args))
+    assert kernel.shape == (B, P)
+    np.testing.assert_array_equal(kernel, ref)
+    np.testing.assert_array_equal(kernel[-1], np.asarray(jitter)[-1])
+
+
+def test_segment_must_tile_the_bucket():
+    from repro.kernels.event_loop import _seg_for
+
+    assert _seg_for(4096, 512) == 512
+    assert _seg_for(8, 512) == 8
+    with pytest.raises(ValueError, match="multiple of 8"):
+        _seg_for(256, 12)
+    with pytest.raises(ValueError, match="divide"):
+        _seg_for(256, 96)
+
+
 # ---------------------------------------------------------------------------
 # campaign scale (slow tier)
 # ---------------------------------------------------------------------------
@@ -205,8 +254,8 @@ def test_lockstep_replay_pallas_bit_identical():
 
 @pytest.mark.slow
 def test_stream_scale_lane_bit_identical():
-    """K = 65536 (the STREAM-scale SS lane the kernel targets): 128
-    sequential segments through the grid axis, still bit-exact."""
+    """K = 65536 (the STREAM-scale SS lane): 128 sequential segments
+    through the grid axis, still bit-exact."""
     sysm = dataclasses.replace(get_system("cascadelake"), P=20)
     prof = LoopProfile(name="u", N=4_194_304, memory_bound=0.3,
                        locality_sens=0.2, c_loc=64, unit=1e-8)

@@ -89,11 +89,13 @@ def test_resolve_async_dispatch(monkeypatch):
 
 
 def test_resolve_event_core_auto():
-    # on this container (CPU) the platform default must stay the while-loop
-    # reference; "auto" is accepted explicitly and via the env default
+    # the platform default: the while-loop reference on CPU, the compiled
+    # kernel on TPU; "auto" is accepted explicitly and via the env default.
+    # No fallback: an explicit core always resolves to itself
     import jax
     expect = "pallas" if jax.default_backend() == "tpu" else "while_loop"
     assert resolve_event_core("auto") == expect
+    assert resolve_event_core("pallas") == "pallas"
     with pytest.raises(ValueError, match="auto"):
         resolve_event_core("triton")
 
@@ -132,7 +134,9 @@ def test_sharded_bit_equality_8_virtual_devices():
     """Lockstep Q-tables/traces, portfolio sweeps and what-if prices must be
     identical on a (data: 8) mesh, a non-divisible (data: 3) mesh and the
     single-device path — see ``tests/_shard_subproc.py``."""
-    env = dict(os.environ,
+    # the child forces virtual host devices: pin it to the CPU so it never
+    # reaches for an accelerator this process may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     env.pop("REPRO_DATA_PARALLEL", None)
     env.pop("REPRO_ASYNC_DISPATCH", None)
