@@ -11,7 +11,6 @@ Benches:
     traces       Figs. 7 & 8 per-instance selection traces
     serving      L3          chunk-scheduled dispatch vs selectors
     autotune     L2          step-plan selection on a real model
-    roofline     §Roofline   three-term roofline per dry-run cell
     backends     §Backends   portfolio sweep: python vs batched JAX engine
     replay       §Backends   lockstep multi-cell replay vs sequential
     event_kernel §Backends   while_loop vs Pallas event core
@@ -158,8 +157,8 @@ def main() -> None:
     from . import (bench_anova, bench_autotune, bench_backends, bench_chunks,
                    bench_cov, bench_degradation, bench_event_kernel,
                    bench_faults, bench_fleet, bench_learned, bench_perturb,
-                   bench_replay, bench_roofline, bench_serving, bench_shard,
-                   bench_simpolicy, bench_traces)
+                   bench_replay, bench_serving, bench_shard, bench_simpolicy,
+                   bench_traces)
     benches = {
         "chunks": bench_chunks.main,
         "cov": bench_cov.main,
@@ -168,7 +167,6 @@ def main() -> None:
         "traces": bench_traces.main,
         "serving": bench_serving.main,
         "autotune": bench_autotune.main,
-        "roofline": bench_roofline.main,
         "backends": bench_backends.main,
         "replay": bench_replay.main,
         "event_kernel": bench_event_kernel.main,

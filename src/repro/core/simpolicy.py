@@ -51,6 +51,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from ..tracing import span
 from .api import Decision, Observation, SelectionPolicy, get_reward
 from .drift import PageHinkley
 from .portfolio import N_ALGORITHMS
@@ -196,43 +197,49 @@ class SimPolicy(SelectionPolicy):
         return list(cands)
 
     def decide(self) -> Decision:
-        try:
-            cands = self._candidate_set()
-            priced = _as_observations(self.simulator.price(cands))
-        except SimUnavailable:
-            self._last_pred = None
-            d = self._fallback.decide()
-            return Decision(action=d.action, phase="expert", confidence=0.0)
-        raw = np.array([self._reward_fn(o) for o in priced],
-                       dtype=np.float64)
-        costs = raw
-        if self.reactive and self._corrections:
-            # live surrogate-fidelity corrections: multiply each candidate's
-            # simulated price by its measured/predicted EMA ratio
-            costs = raw * np.array(
-                [self._corrections.get((c.alg, c.chunk_param), 1.0)
-                 for c in cands], dtype=np.float64)
-        best = int(np.argmin(costs))
-        lo, hi = float(costs[best]), float(costs.max())
-        spread = (hi - lo) / max(abs(hi), 1e-12)
-        if spread < self.confidence_threshold:
-            # indistinguishable candidates: the prediction carries no signal
-            d = self._fallback.decide()
-            self._last_pred = None
-            self._last_key = None
-            return Decision(action=d.action, phase="expert",
-                            confidence=d.confidence)
-        # committed: confidence is the relative margin to the runner-up
-        second = float(np.partition(costs, 1)[1]) if len(costs) > 1 else hi
-        conf = float(np.clip((second - lo) / max(abs(second), 1e-12), 0, 1))
-        # fidelity bookkeeping uses the RAW simulated price of the committed
-        # candidate (corrections must calibrate against the simulator, not
-        # against themselves)
-        self._last_pred = float(raw[best])
-        self._last_key = (cands[best].alg, cands[best].chunk_param)
-        return Decision(action=cands[best].alg,
-                        chunk_param=cands[best].chunk_param,
-                        phase="exploit", confidence=conf)
+        with span("simpolicy.decide") as sp:
+            try:
+                cands = self._candidate_set()
+                sp.set_metadata(candidates=len(cands))
+                priced = _as_observations(self.simulator.price(cands))
+            except SimUnavailable:
+                self._last_pred = None
+                d = self._fallback.decide()
+                return Decision(action=d.action, phase="expert",
+                                confidence=0.0)
+            raw = np.array([self._reward_fn(o) for o in priced],
+                           dtype=np.float64)
+            costs = raw
+            if self.reactive and self._corrections:
+                # live surrogate-fidelity corrections: multiply each
+                # candidate's simulated price by its measured/predicted EMA
+                # ratio
+                costs = raw * np.array(
+                    [self._corrections.get((c.alg, c.chunk_param), 1.0)
+                     for c in cands], dtype=np.float64)
+            best = int(np.argmin(costs))
+            lo, hi = float(costs[best]), float(costs.max())
+            spread = (hi - lo) / max(abs(hi), 1e-12)
+            if spread < self.confidence_threshold:
+                # indistinguishable candidates: the prediction carries no
+                # signal
+                d = self._fallback.decide()
+                self._last_pred = None
+                self._last_key = None
+                return Decision(action=d.action, phase="expert",
+                                confidence=d.confidence)
+            # committed: confidence is the relative margin to the runner-up
+            second = float(np.partition(costs, 1)[1]) if len(costs) > 1 else hi
+            conf = float(np.clip((second - lo) / max(abs(second), 1e-12),
+                                 0, 1))
+            # fidelity bookkeeping uses the RAW simulated price of the
+            # committed candidate (corrections must calibrate against the
+            # simulator, not against themselves)
+            self._last_pred = float(raw[best])
+            self._last_key = (cands[best].alg, cands[best].chunk_param)
+            return Decision(action=cands[best].alg,
+                            chunk_param=cands[best].chunk_param,
+                            phase="exploit", confidence=conf)
 
     def feedback(self, decision: Decision, obs: Observation) -> None:
         # keep the fallback ladder tracking the live trajectory

@@ -141,6 +141,7 @@ def event_finish(eff, speed, jitter, h_eff, bcost, forced, count, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="event_finish",
     )(nmax, lanes_last(eff), lanes_last(forced, -1), lanes_last(speed),
       lanes_last(jitter), lanes_last(jnp.stack([h_eff, bcost], axis=1)),
       count.reshape(1, Bp))

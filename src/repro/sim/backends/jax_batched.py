@@ -77,6 +77,7 @@ from ...core.jaxsched import (chunk_schedule, staticsteal_schedule,
 from ...core.portfolio import ADAPTIVE_SET
 from ...distributed.sharding import lane_count, lane_spec, pad_lanes
 from ...launch.mesh import campaign_mesh
+from ...tracing import span
 from ..workloads import profile_digest as _profile_digest
 from ..workloads import stack_prefix_grids
 from .base import (BatchResult, InstancePerturb, InstanceSpec, LockstepRequest,
@@ -163,18 +164,24 @@ def resolve_adaptive_reweight(adaptive_reweight: Optional[bool] = None
 
 class _LRU:
     """Tiny LRU mapping bounding the process-wide caches (schedules, steal
-    replays, device-resident grid stacks) of the singleton backend."""
+    replays, device-resident grid stacks) of the singleton backend.  Every
+    cache counts its hits and misses; the trace reports those of the
+    schedule and steal caches (``repro.events.rows``), and the grid cache's
+    are kept for a debugger alone."""
 
     def __init__(self, maxsize: int):
         self.maxsize = maxsize
         self._d: OrderedDict = OrderedDict()
+        self.hits = self.misses = 0
 
     def get(self, key, default=None):
         try:
             self._d.move_to_end(key)
-            return self._d[key]
         except KeyError:
+            self.misses += 1
             return default
+        self.hits += 1
+        return self._d[key]
 
     def put(self, key, value) -> None:
         self._d[key] = value
@@ -250,11 +257,6 @@ def _batched_events_impl(P: int, core: str, grids, grid_id, inv_n, starts,
         noise = jnp.exp((sigma * ss) * jax.random.normal(kn, (K,)))
         return jitter, speed, noise
 
-    jitter, speed, noise = jax.vmap(draws)(seeds, sig_scale)
-    # perturbation / heterogeneity enters HERE, in the shared precompute —
-    # upstream of every event core, so while_loop and Pallas stay identical
-    speed = speed * pe_mult
-
     def eff_one(gid, gs, starts, sizes, loc, noise):
         def pref(x):
             pos = x.astype(jnp.float32) * gs
@@ -264,10 +266,21 @@ def _batched_events_impl(P: int, core: str, grids, grid_id, inv_n, starts,
 
         return (pref(starts + sizes) - pref(starts)) * loc * noise
 
-    eff = jax.vmap(eff_one)(grid_id, G * inv_n, starts, sizes, loc, noise)
-    fin = _core_finish(core, eff, speed, jitter, h_eff, bcost, forced, count)
-    mk = fin.max(axis=1)
-    lib = jnp.where(mk > 0.0, (1.0 - fin.mean(axis=1) / mk) * 100.0, 0.0)
+    # the scopes name the device operations in a profile; metadata only
+    with jax.named_scope("precompute"):
+        jitter, speed, noise = jax.vmap(draws)(seeds, sig_scale)
+        # perturbation / heterogeneity enters HERE, in the shared
+        # precompute — upstream of every event core, so while_loop and
+        # Pallas stay identical
+        speed = speed * pe_mult
+        eff = jax.vmap(eff_one)(grid_id, G * inv_n, starts, sizes, loc,
+                                noise)
+    with jax.named_scope("event_core"):
+        fin = _core_finish(core, eff, speed, jitter, h_eff, bcost, forced,
+                           count)
+        mk = fin.max(axis=1)
+        lib = jnp.where(mk > 0.0, (1.0 - fin.mean(axis=1) / mk) * 100.0,
+                        0.0)
     return mk, lib, fin
 
 
@@ -443,13 +456,15 @@ class JaxBatchedBackend(SimBackend):
             return hit
         guess = -(-N // max(1, cp)) if alg == 1 else 256
         mc = _next_bucket(min(guess, _K_BUCKETS[-1]))
-        while True:
-            sizes, count = chunk_schedule(alg, N, P, cp, max_chunks=mc)
-            # slice host-side: eager jnp slicing compiles per output shape
-            sizes = np.asarray(sizes, dtype=np.int64)[: int(count)]
-            if sizes.sum() == N or mc >= _K_BUCKETS[-1]:
-                break
-            mc = _next_bucket(mc + 1)       # truncated: retry wider buffer
+        with span("sched.build", kind=0):
+            while True:
+                sizes, count = chunk_schedule(alg, N, P, cp, max_chunks=mc)
+                # slice host-side: eager jnp slicing compiles per output
+                # shape
+                sizes = np.asarray(sizes, dtype=np.int64)[: int(count)]
+                if sizes.sum() == N or mc >= _K_BUCKETS[-1]:
+                    break
+                mc = _next_bucket(mc + 1)   # truncated: retry wider buffer
         if sizes.sum() != N:
             raise RuntimeError(
                 f"schedule truncated: alg={alg} N={N} P={P} cp={cp}")
@@ -468,23 +483,24 @@ class JaxBatchedBackend(SimBackend):
         ls = profile.locality_sens
         mc = _next_bucket(min(-(-N // max(1, cp)) + 8 * P * 34,
                               _K_BUCKETS[-1]))
-        while True:
-            starts, sizes, pes, own, count = staticsteal_schedule(
-                N, P, cp, max_chunks=mc, unit=unit, h=system.h,
-                bcost=profile.memory_bound * system.boundary_cost,
-                base_infl=1.0 + ls * system.dyn_locality,
-                amp=ls * system.loc_amp, c_loc=float(profile.c_loc))
-            count = int(count)
-            sizes_np = np.asarray(sizes, dtype=np.int64)[:count]
-            if sizes_np.sum() == N or mc >= _K_BUCKETS[-1]:
-                break
-            mc = _next_bucket(mc + 1)
-        if sizes_np.sum() != N:
-            raise RuntimeError(f"steal schedule truncated: N={N} P={P}")
-        out = (np.asarray(starts, np.int32)[:count],
-               sizes_np.astype(np.int32),
-               np.asarray(pes, np.int32)[:count],
-               np.asarray(own)[:count])
+        with span("sched.build", kind=1):
+            while True:
+                starts, sizes, pes, own, count = staticsteal_schedule(
+                    N, P, cp, max_chunks=mc, unit=unit, h=system.h,
+                    bcost=profile.memory_bound * system.boundary_cost,
+                    base_infl=1.0 + ls * system.dyn_locality,
+                    amp=ls * system.loc_amp, c_loc=float(profile.c_loc))
+                count = int(count)
+                sizes_np = np.asarray(sizes, dtype=np.int64)[:count]
+                if sizes_np.sum() == N or mc >= _K_BUCKETS[-1]:
+                    break
+                mc = _next_bucket(mc + 1)
+            if sizes_np.sum() != N:
+                raise RuntimeError(f"steal schedule truncated: N={N} P={P}")
+            out = (np.asarray(starts, np.int32)[:count],
+                   sizes_np.astype(np.int32),
+                   np.asarray(pes, np.int32)[:count],
+                   np.asarray(own)[:count])
         if cache:
             self._steal_cache.put(key, out)
         return out
@@ -502,7 +518,8 @@ class JaxBatchedBackend(SimBackend):
         key = (alg, N, P, cp, wkey)
         hit = self._sched_cache.get(key)
         if hit is None:
-            hit = weighted_adaptive_schedule(alg, N, P, cp, w)
+            with span("sched.build", kind=2):
+                hit = weighted_adaptive_schedule(alg, N, P, cp, w)
             self._sched_cache.put(key, hit)
         return hit
 
@@ -566,59 +583,73 @@ class JaxBatchedBackend(SimBackend):
         lib = np.zeros(B)
         nc = np.zeros(B, np.int64)
         event_ids: List[int] = []
-        for i, s in enumerate(specs):
-            profile = profiles[s.profile_id]
-            if s.alg == 0 or needs_closed_form(s.alg, profile.N,
-                                               s.chunk_param):
-                rng = np.random.default_rng(s.seed)
-                r = _py_run_instance(profile, system, s.alg, s.chunk_param,
-                                     rng, perturb=s.perturb)
-                lt[i], lib[i], nc[i] = r.loop_time, r.lib, r.n_chunks
-            else:
-                event_ids.append(i)
-        if event_ids:
-            mks, libs, _, counts = self._run_events(
-                profiles, system, [specs[i] for i in event_ids])
-            for j, i in enumerate(event_ids):
-                lt[i], lib[i], nc[i] = mks[j], libs[j], counts[j]
+        with span("backend.batch", instances=B):
+            with span("backend.host_instances") as sp:
+                for i, s in enumerate(specs):
+                    profile = profiles[s.profile_id]
+                    if s.alg == 0 or needs_closed_form(s.alg, profile.N,
+                                                       s.chunk_param):
+                        rng = np.random.default_rng(s.seed)
+                        r = _py_run_instance(profile, system, s.alg,
+                                             s.chunk_param, rng,
+                                             perturb=s.perturb)
+                        lt[i], lib[i], nc[i] = r.loop_time, r.lib, r.n_chunks
+                    else:
+                        event_ids.append(i)
+                sp.set_metadata(closed=B - len(event_ids),
+                                event=len(event_ids))
+            if event_ids:
+                mks, libs, _, counts = self._run_events(
+                    profiles, system, [specs[i] for i in event_ids])
+                for j, i in enumerate(event_ids):
+                    lt[i], lib[i], nc[i] = mks[j], libs[j], counts[j]
         return BatchResult(loop_time=lt, lib=lib, n_chunks=nc)
 
     def _run_events(self, profiles, system, specs):
         """Evaluate event-loop instances; returns (mk, lib, finish, count)
         arrays in spec order."""
         P = system.P
-        grids_dev = self._grids_dev(profiles)
-        rows = [self._event_rows(s, profiles[s.profile_id], system)
-                for s in specs]
-        counts = np.array([len(r[1]) for r in rows], np.int32)
         B = len(specs)
         mk = np.zeros(B)
         lb = np.zeros(B)
         fin = np.zeros((B, P))
+        sched, steal = self._sched_cache, self._steal_cache
+        hits0 = (sched.hits, sched.misses, steal.hits, steal.misses)
+        with span("events.rows") as sp:
+            grids_dev = self._grids_dev(profiles)
+            rows = [self._event_rows(s, profiles[s.profile_id], system)
+                    for s in specs]
+            counts = np.array([len(r[1]) for r in rows], np.int32)
 
-        # per-spec scalar lanes (gathered per bucket below)
-        gid_all = np.fromiter((s.profile_id for s in specs), np.int32, B)
-        inv_all = np.fromiter((1.0 / profiles[s.profile_id].N
-                               for s in specs), np.float32, B)
-        seed_all = np.fromiter((s.fold_seed() for s in specs), np.uint32, B)
-        h_all = np.fromiter((_h_eff(system, s.alg) for s in specs),
-                            np.float32, B)
-        bc_all = np.fromiter(
-            (profiles[s.profile_id].memory_bound * system.boundary_cost
-             for s in specs), np.float32, B)
-        # perturbation lanes: per-PE multipliers and sigma scales (rows stay
-        # exactly 1.0 for clean lanes — IEEE-identity multiplies downstream)
-        pm_all = np.ones((B, P), np.float32)
-        ss_all = np.ones(B, np.float32)
-        for i, s in enumerate(specs):
-            scale = combined_pe_scale(system, s.perturb)
-            if scale is not None:
-                pm_all[i] = scale
-            ss_all[i] = sigma_scale_of(s.perturb)
+            # per-spec scalar lanes (gathered per bucket below)
+            gid_all = np.fromiter((s.profile_id for s in specs), np.int32, B)
+            inv_all = np.fromiter((1.0 / profiles[s.profile_id].N
+                                   for s in specs), np.float32, B)
+            seed_all = np.fromiter((s.fold_seed() for s in specs),
+                                   np.uint32, B)
+            h_all = np.fromiter((_h_eff(system, s.alg) for s in specs),
+                                np.float32, B)
+            bc_all = np.fromiter(
+                (profiles[s.profile_id].memory_bound * system.boundary_cost
+                 for s in specs), np.float32, B)
+            # perturbation lanes: per-PE multipliers and sigma scales (rows
+            # stay exactly 1.0 for clean lanes — IEEE-identity multiplies
+            # downstream)
+            pm_all = np.ones((B, P), np.float32)
+            ss_all = np.ones(B, np.float32)
+            for i, s in enumerate(specs):
+                scale = combined_pe_scale(system, s.perturb)
+                if scale is not None:
+                    pm_all[i] = scale
+                ss_all[i] = sigma_scale_of(s.perturb)
 
-        by_bucket: Dict[int, List[int]] = {}
-        for i, c in enumerate(counts):
-            by_bucket.setdefault(_next_bucket(int(c)), []).append(i)
+            by_bucket: Dict[int, List[int]] = {}
+            for i, c in enumerate(counts):
+                by_bucket.setdefault(_next_bucket(int(c)), []).append(i)
+            sp.set_metadata(sched_hits=sched.hits - hits0[0],
+                            sched_misses=sched.misses - hits0[1],
+                            steal_hits=steal.hits - hits0[2],
+                            steal_misses=steal.misses - hits0[3])
 
         def packed():
             """Host-side ragged-to-padded assembly, one yielded batch per
@@ -631,46 +662,51 @@ class JaxBatchedBackend(SimBackend):
                     sub = np.asarray(ids[off:off + max_rows])
                     n = len(sub)
                     Bp = self._pad_rows(n)
-                    # ragged-to-padded assembly: one boolean scatter per
-                    # field instead of per-row element-wise packing loops
                     lens = counts[sub]
-                    mask = (np.arange(K, dtype=np.int32)[None, :]
-                            < lens[:, None])
-                    starts = np.zeros((Bp, K), np.int32)
-                    sizes = np.zeros((Bp, K), np.int32)
-                    loc = np.zeros((Bp, K), np.float32)
-                    forced = np.full((Bp, K), -1, np.int32)
-                    starts[:n][mask] = np.concatenate(
-                        [rows[i][0] for i in sub])
-                    sizes[:n][mask] = np.concatenate(
-                        [rows[i][1] for i in sub])
-                    loc[:n][mask] = np.concatenate([rows[i][2] for i in sub])
-                    forced[:n][mask] = np.concatenate(
-                        [rows[i][3] if rows[i][3] is not None
-                         else np.full(lens[j], -1, np.int32)
-                         for j, i in enumerate(sub)])
-                    gid = np.zeros(Bp, np.int32)
-                    inv_n = np.ones(Bp, np.float32)
-                    cnt = np.zeros(Bp, np.int32)
-                    seeds = np.zeros(Bp, np.uint32)
-                    h_eff = np.zeros(Bp, np.float32)
-                    bcost = np.zeros(Bp, np.float32)
-                    pe_mult = np.ones((Bp, P), np.float32)
-                    sscale = np.ones(Bp, np.float32)
-                    gid[:n] = gid_all[sub]
-                    inv_n[:n] = inv_all[sub]
-                    cnt[:n] = lens
-                    seeds[:n] = seed_all[sub]
-                    h_eff[:n] = h_all[sub]
-                    bcost[:n] = bc_all[sub]
-                    pe_mult[:n] = pm_all[sub]
-                    sscale[:n] = ss_all[sub]
-                    yield sub, (gid, inv_n, starts, sizes, loc, cnt, forced,
-                                seeds, h_eff, bcost, pe_mult, sscale)
+                    with span("events.pack", K=K, rows=Bp, real=n,
+                              chunks=int(lens.sum())):
+                        lanes = pack(sub, lens, Bp, K)
+                    yield sub, lanes
+
+        def pack(sub, lens, Bp, K):
+            # ragged-to-padded assembly: one boolean scatter per field
+            # instead of per-row element-wise packing loops
+            n = len(sub)
+            mask = np.arange(K, dtype=np.int32)[None, :] < lens[:, None]
+            starts = np.zeros((Bp, K), np.int32)
+            sizes = np.zeros((Bp, K), np.int32)
+            loc = np.zeros((Bp, K), np.float32)
+            forced = np.full((Bp, K), -1, np.int32)
+            starts[:n][mask] = np.concatenate([rows[i][0] for i in sub])
+            sizes[:n][mask] = np.concatenate([rows[i][1] for i in sub])
+            loc[:n][mask] = np.concatenate([rows[i][2] for i in sub])
+            forced[:n][mask] = np.concatenate(
+                [rows[i][3] if rows[i][3] is not None
+                 else np.full(lens[j], -1, np.int32)
+                 for j, i in enumerate(sub)])
+            gid = np.zeros(Bp, np.int32)
+            inv_n = np.ones(Bp, np.float32)
+            cnt = np.zeros(Bp, np.int32)
+            seeds = np.zeros(Bp, np.uint32)
+            h_eff = np.zeros(Bp, np.float32)
+            bcost = np.zeros(Bp, np.float32)
+            pe_mult = np.ones((Bp, P), np.float32)
+            sscale = np.ones(Bp, np.float32)
+            gid[:n] = gid_all[sub]
+            inv_n[:n] = inv_all[sub]
+            cnt[:n] = lens
+            seeds[:n] = seed_all[sub]
+            h_eff[:n] = h_all[sub]
+            bcost[:n] = bc_all[sub]
+            pe_mult[:n] = pm_all[sub]
+            sscale[:n] = ss_all[sub]
+            return (gid, inv_n, starts, sizes, loc, cnt, forced, seeds, h_eff,
+                    bcost, pe_mult, sscale)
 
         def drain(sub, res):
             n = len(sub)
-            m, l, f = (np.asarray(x) for x in res)
+            with span("events.wait", rows=res[0].shape[0]):
+                m, l, f = (np.asarray(x) for x in res)
             mk[sub], lb[sub], fin[sub] = m[:n], l[:n], f[:n]
 
         # double-buffered async dispatch: jax dispatch is asynchronous, so
@@ -682,10 +718,13 @@ class JaxBatchedBackend(SimBackend):
         # itself stays rejected — see the note above the jitted cores).
         pending = None
         for sub, lanes in packed():
-            res = self._events_call(
-                P, grids_dev, *lanes,
-                np.float32(system.noise_sigma), np.float32(system.jitter),
-                np.float32(system.speed_spread))
+            with span("events.dispatch", P=P, K=lanes[2].shape[1],
+                      rows=lanes[2].shape[0], grid_rows=grids_dev.shape[0],
+                      grid_cols=grids_dev.shape[1]):
+                res = self._events_call(
+                    P, grids_dev, *lanes,
+                    np.float32(system.noise_sigma), np.float32(system.jitter),
+                    np.float32(system.speed_spread))
             if not self.async_dispatch:
                 drain(sub, res)
                 continue
@@ -715,23 +754,29 @@ class JaxBatchedBackend(SimBackend):
         nc = np.zeros(B, np.int64)
         event_ids: List[int] = []
         specs: List[InstanceSpec] = []
-        for i, q in enumerate(requests):
-            profile = profiles[q.profile_id]
-            if q.alg == 0 or needs_closed_form(q.alg, profile.N,
-                                               q.chunk_param):
-                r = _py_run_instance(profile, system, q.alg, q.chunk_param,
-                                     q.rng, perturb=q.perturb)
-                lt[i], lib[i], nc[i] = r.loop_time, r.lib, r.n_chunks
-            else:
-                seed = (int(q.rng.integers(0, 2**31 - 1)),)
-                specs.append(InstanceSpec(profile_id=q.profile_id, alg=q.alg,
-                                          chunk_param=q.chunk_param,
-                                          seed=seed, perturb=q.perturb))
-                event_ids.append(i)
-        if specs:
-            mks, libs, _, counts = self._run_events(profiles, system, specs)
-            for j, i in enumerate(event_ids):
-                lt[i], lib[i], nc[i] = mks[j], libs[j], counts[j]
+        with span("backend.lockstep", instances=B):
+            with span("backend.host_instances") as sp:
+                for i, q in enumerate(requests):
+                    profile = profiles[q.profile_id]
+                    if q.alg == 0 or needs_closed_form(q.alg, profile.N,
+                                                       q.chunk_param):
+                        r = _py_run_instance(profile, system, q.alg,
+                                             q.chunk_param, q.rng,
+                                             perturb=q.perturb)
+                        lt[i], lib[i], nc[i] = r.loop_time, r.lib, r.n_chunks
+                    else:
+                        seed = (int(q.rng.integers(0, 2**31 - 1)),)
+                        specs.append(InstanceSpec(
+                            profile_id=q.profile_id, alg=q.alg,
+                            chunk_param=q.chunk_param, seed=seed,
+                            perturb=q.perturb))
+                        event_ids.append(i)
+                sp.set_metadata(closed=B - len(specs), event=len(specs))
+            if specs:
+                mks, libs, _, counts = self._run_events(profiles, system,
+                                                        specs)
+                for j, i in enumerate(event_ids):
+                    lt[i], lib[i], nc[i] = mks[j], libs[j], counts[j]
         return BatchResult(loop_time=lt, lib=lib, n_chunks=nc)
 
     # ---- single instance (selector path) ----------------------------------

@@ -41,6 +41,7 @@ from ..core.learned import LoopFeaturizer
 from ..core.simpolicy import _SIM_ALIASES
 from .backends import (InstancePerturb, InstanceSpec, LockstepRequest,
                        get_backend)
+from ..tracing import span
 from .perturb import PerturbationSpec
 from .whatif import LoopWhatIf
 from .systems import SystemModel, get_system
@@ -480,43 +481,49 @@ class ReplayBatch:
 
     def step(self, t: int) -> None:
         """One decide / execute / learn cycle over all active lanes."""
-        loops_cache: Dict[Tuple, List] = {}
-        groups: Dict[str, _StepGroup] = {}
-        for lane in self.lanes:                               # decide
-            if t >= lane.T:
-                continue
-            g = groups.get(lane.spec.system)
-            if g is None:
-                g = groups[lane.spec.system] = _StepGroup(lane.system)
-            pz = lane.spec.perturb
-            drift = pz if (pz is not None and pz.has_drift) else None
-            ip = lane.perturb_at(t)
-            loops = self._loops(loops_cache, lane.spec.app, t, drift)
-            pids = g.register((lane.spec.app, drift), loops)
-            for li, profile in enumerate(loops):
-                cp = chunk_param_for(lane.spec.chunk_mode, profile.N,
-                                     lane.system.P)
-                if lane.whatif is not None:
-                    lane.whatif.set_context(profile, cp, perturb=ip)
-                inst = lane.service.instance(lane.app.loop_names[li])
-                d = inst.decision.with_instance_defaults(cp)
-                g.requests.append(LockstepRequest(
-                    profile_id=pids[li], alg=d.action,
-                    chunk_param=d.chunk_param, rng=lane.rng, perturb=ip))
-                g.pending.append((lane, inst))
-                if self.translog is not None:
-                    g.trans.append(self.translog.log_decision(
-                        lane, t, profile, cp, ip, d))
-        for g in groups.values():                             # execute
-            res = self.bk.run_lockstep(g.profiles, g.system, g.requests)
-            obs = Observation.batch(res.loop_time, res.lib)
-            for i, ((lane, inst), o) in enumerate(zip(g.pending,
-                                                      obs)):  # learn
-                inst.report(observation=o)
-                inst.close()
-                lane.total += o.loop_time
-                if g.trans and g.trans[i] is not None:
-                    self.translog.log_result(g.trans[i], o.loop_time)
+        with span("replay.step", t=t, lanes=len(self.lanes)):
+            loops_cache: Dict[Tuple, List] = {}
+            groups: Dict[str, _StepGroup] = {}
+            with span("replay.decide") as sp:
+                for lane in self.lanes:                           # decide
+                    if t >= lane.T:
+                        continue
+                    g = groups.get(lane.spec.system)
+                    if g is None:
+                        g = groups[lane.spec.system] = _StepGroup(lane.system)
+                    pz = lane.spec.perturb
+                    drift = pz if (pz is not None and pz.has_drift) else None
+                    ip = lane.perturb_at(t)
+                    loops = self._loops(loops_cache, lane.spec.app, t, drift)
+                    pids = g.register((lane.spec.app, drift), loops)
+                    for li, profile in enumerate(loops):
+                        cp = chunk_param_for(lane.spec.chunk_mode, profile.N,
+                                             lane.system.P)
+                        if lane.whatif is not None:
+                            lane.whatif.set_context(profile, cp, perturb=ip)
+                        inst = lane.service.instance(lane.app.loop_names[li])
+                        d = inst.decision.with_instance_defaults(cp)
+                        g.requests.append(LockstepRequest(
+                            profile_id=pids[li], alg=d.action,
+                            chunk_param=d.chunk_param, rng=lane.rng,
+                            perturb=ip))
+                        g.pending.append((lane, inst))
+                        if self.translog is not None:
+                            g.trans.append(self.translog.log_decision(
+                                lane, t, profile, cp, ip, d))
+                sp.set_metadata(requests=sum(len(g.requests)
+                                             for g in groups.values()))
+            for g in groups.values():                             # execute
+                res = self.bk.run_lockstep(g.profiles, g.system, g.requests)
+                with span("replay.learn", lanes=len(g.pending)):
+                    obs = Observation.batch(res.loop_time, res.lib)
+                    for i, ((lane, inst), o) in enumerate(zip(g.pending,
+                                                              obs)):  # learn
+                        inst.report(observation=o)
+                        inst.close()
+                        lane.total += o.loop_time
+                        if g.trans and g.trans[i] is not None:
+                            self.translog.log_result(g.trans[i], o.loop_time)
 
     def run(self) -> List[SelectorRun]:
         """Replay every lane to completion; results in lane order."""

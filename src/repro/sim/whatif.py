@@ -32,6 +32,7 @@ from typing import List, Optional, Sequence
 from ..core import exp_chunk
 from ..core.api import Observation
 from ..core.simpolicy import Candidate, SimUnavailable
+from ..tracing import span
 from .backends import InstancePerturb, InstanceSpec, get_backend
 from .workloads import profile_digest
 
@@ -105,19 +106,21 @@ class LoopWhatIf:
         # patterns share N*unit totals across time steps, so cheap fields
         # alone would alias genuinely different load distributions.  The
         # perturbation key keeps perturbed prices from aliasing clean ones.
-        key = (p.name, profile_digest(p), p.unit, p.memory_bound,
-               p.locality_sens, p.c_loc, resolved,
-               None if perturb is None else perturb.key())
-        hit = self._cache.get(key)
-        if hit is not None:
-            self._cache.move_to_end(key)
-            return hit
-        specs = [InstanceSpec(profile_id=0, alg=a, chunk_param=cp,
-                              seed=_PRICE_SEED + (a, cp), perturb=perturb)
-                 for a, cp in resolved]
-        res = self.bk.run_batch([p], self.system, specs)
-        out = [Observation(loop_time=float(t), lib=float(b))
-               for t, b in zip(res.loop_time, res.lib)]
+        with span("whatif.price") as sp:
+            key = (p.name, profile_digest(p), p.unit, p.memory_bound,
+                   p.locality_sens, p.c_loc, resolved,
+                   None if perturb is None else perturb.key())
+            hit = self._cache.get(key)
+            sp.set_metadata(cached=int(hit is not None))
+            if hit is not None:
+                self._cache.move_to_end(key)
+                return hit
+            specs = [InstanceSpec(profile_id=0, alg=a, chunk_param=cp,
+                                  seed=_PRICE_SEED + (a, cp), perturb=perturb)
+                     for a, cp in resolved]
+            res = self.bk.run_batch([p], self.system, specs)
+            out = [Observation(loop_time=float(t), lib=float(b))
+                   for t, b in zip(res.loop_time, res.lib)]
         self._cache[key] = out
         if len(self._cache) > _CACHE_SIZE:
             self._cache.popitem(last=False)
