@@ -14,7 +14,10 @@ rep x time-step) — at once:
 2.  Everything data-parallel runs in ONE vectorized precompute shared by
     every event core: gathered linear interpolation over the stacked prefix
     grids (device upload cached per profile stack), locality inflation, and
-    the counter-based jitter/speed/log-normal-noise draws.
+    the counter-based jitter/speed/log-normal-noise draws.  The
+    interpolation runs only over the (rows x segment) tiles of the padded
+    (B, K) batch that some lane's chunk count reaches; every other slot is
+    0.0, as the dense formula gives there.
 3.  The sequential event loop itself is a minimal pluggable core
     ``(eff_costs, forced, count) -> finish`` with two interchangeable
     implementations: the vmapped ``lax.while_loop`` reference (argmin
@@ -62,6 +65,7 @@ STATIC branch.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -91,6 +95,9 @@ from .python import InstanceResult, _h_eff, run_instance as _py_run_instance
 _K_BUCKETS = (256, 1024, 4096, 16384, 65536, 262144)
 #: max elements per (B, K) device array in one call (~16 MB float32)
 _MAX_ELEMS = 1 << 22
+#: the precompute's tile: lanes (the f32 sublane tile) x chunk segment
+_TILE_ROWS = 8
+_TILE_SEG = 512     # the event kernel's segment (kernels/event_loop.py)
 
 #: env var naming the default sequential event core
 EVENT_CORE_ENV = "REPRO_EVENT_CORE"
@@ -119,6 +126,23 @@ def _pow2_rows(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _tile_shape(B: int, K: int) -> Tuple[int, int]:
+    """(rows, seg) of one precompute tile of a (B, K) lane block: both
+    divide the block's sides."""
+    return math.gcd(B, _TILE_ROWS), math.gcd(K, _TILE_SEG)
+
+
+def _live_slots(lens: np.ndarray, B: int, K: int, shards: int = 1) -> int:
+    """Slots of a (B, K) dispatch inside the precompute's live tiles, its
+    first ``len(lens)`` lanes holding ``lens`` chunks and the rest none;
+    each of ``shards`` equal lane blocks is tiled on its own."""
+    rows, seg = _tile_shape(B // shards, K)
+    top = np.zeros(B, np.int64)
+    top[:len(lens)] = lens
+    return int((-(-top.reshape(-1, rows).max(axis=1) // seg)).sum()
+               ) * rows * seg
 
 
 def resolve_event_core(kernel: Optional[str] = None) -> str:
@@ -229,6 +253,48 @@ def _core_finish(core: str, eff, speed, jitter, h_eff, bcost, forced,
     return _core_while(eff, speed, jitter, h_eff, bcost, forced, count)
 
 
+def _effective_costs(grids, gs, grid_id, starts, sizes, loc, noise, count):
+    """Per-chunk effective costs (B, K): the prefix-grid interpolation of
+    each chunk's work, times its locality inflation and noise.
+
+    Only the (rows x seg) tiles that some lane of their row block reaches
+    (``count``) are computed, one tile per loop step; every other slot
+    holds no chunk (starts = sizes = loc = 0), where the formula gives
+    0.0, so the result is the dense one bit for bit.
+
+    The operations go under the scope ``precompute``, the loop itself does
+    not: a device trace shows a while loop as one operation around those
+    of its body, which would count the body twice."""
+    B, K = starts.shape
+    G = grids.shape[1] - 1
+    rows, seg = _tile_shape(B, K)
+    with jax.named_scope("precompute"):
+        n = (count.reshape(-1, rows).max(axis=1) + seg - 1) // seg
+        ends = jnp.cumsum(n)
+        tiles, zeros = ends[-1], jnp.zeros((B, K), jnp.float32)
+
+    def tile(t, eff):
+        with jax.named_scope("precompute"):
+            blk = jnp.sum(ends <= t).astype(jnp.int32)
+            r0 = blk * rows
+            c0 = (t - ends[blk] + n[blk]) * seg
+            cut = lambda x: lax.dynamic_slice(x, (r0, c0), (rows, seg))
+            gid = lax.dynamic_slice(grid_id, (r0,), (rows,))[:, None]
+            g = lax.dynamic_slice(gs, (r0,), (rows,))[:, None]
+
+            def pref(x):
+                pos = x.astype(jnp.float32) * g
+                i = jnp.clip(pos.astype(jnp.int32), 0, G - 1)
+                lo = grids[gid, i]
+                return lo + (pos - i) * (grids[gid, i + 1] - lo)
+
+            st, sz = cut(starts), cut(sizes)
+            out = (pref(st + sz) - pref(st)) * cut(loc) * cut(noise)
+            return lax.dynamic_update_slice(eff, out, (r0, c0))
+
+    return lax.fori_loop(0, tiles, tile, zeros)
+
+
 def _batched_events_impl(P: int, core: str, grids, grid_id, inv_n, starts,
                          sizes, loc, count, forced, seeds, h_eff, bcost,
                          pe_mult, sig_scale, sigma, jitter_max,
@@ -257,15 +323,6 @@ def _batched_events_impl(P: int, core: str, grids, grid_id, inv_n, starts,
         noise = jnp.exp((sigma * ss) * jax.random.normal(kn, (K,)))
         return jitter, speed, noise
 
-    def eff_one(gid, gs, starts, sizes, loc, noise):
-        def pref(x):
-            pos = x.astype(jnp.float32) * gs
-            i = jnp.clip(pos.astype(jnp.int32), 0, G - 1)
-            lo = grids[gid, i]
-            return lo + (pos - i) * (grids[gid, i + 1] - lo)
-
-        return (pref(starts + sizes) - pref(starts)) * loc * noise
-
     # the scopes name the device operations in a profile; metadata only
     with jax.named_scope("precompute"):
         jitter, speed, noise = jax.vmap(draws)(seeds, sig_scale)
@@ -273,8 +330,9 @@ def _batched_events_impl(P: int, core: str, grids, grid_id, inv_n, starts,
         # precompute — upstream of every event core, so while_loop and
         # Pallas stay identical
         speed = speed * pe_mult
-        eff = jax.vmap(eff_one)(grid_id, G * inv_n, starts, sizes, loc,
-                                noise)
+        gs = G * inv_n
+    eff = _effective_costs(grids, gs, grid_id, starts, sizes, loc, noise,
+                           count)
     with jax.named_scope("event_core"):
         fin = _core_finish(core, eff, speed, jitter, h_eff, bcost, forced,
                            count)
@@ -664,7 +722,8 @@ class JaxBatchedBackend(SimBackend):
                     Bp = self._pad_rows(n)
                     lens = counts[sub]
                     with span("events.pack", K=K, rows=Bp, real=n,
-                              chunks=int(lens.sum())):
+                              chunks=int(lens.sum()),
+                              live=_live_slots(lens, Bp, K, self._shards)):
                         lanes = pack(sub, lens, Bp, K)
                     yield sub, lanes
 

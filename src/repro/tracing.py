@@ -33,7 +33,8 @@ SPANS = {
     "events.rows": ("sched_hits", "sched_misses", "steal_hits",
                     "steal_misses"),
     "sched.build": ("kind",),           # 0 central, 1 steal, 2 weighted
-    "events.pack": ("K", "rows", "real", "chunks"),
+    # live: the slots of the precompute's tiles that some chunk reaches
+    "events.pack": ("K", "rows", "real", "chunks", "live"),
     # with the grid stack's shape, the program's shapes
     "events.dispatch": ("P", "K", "rows", "grid_rows", "grid_cols"),
     "events.wait": ("rows",),

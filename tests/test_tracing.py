@@ -116,6 +116,8 @@ def test_stats_are_consistent(traced):
     for s in by["events.pack"]:
         assert 0 < s["real"] <= s["rows"]
         assert s["real"] <= s["chunks"] <= s["real"] * s["K"]
+        # the precompute's live tiles hold every chunk, within the batch
+        assert s["chunks"] <= s["live"] <= s["rows"] * s["K"]
     for s in by["events.dispatch"]:
         assert s["P"] == get_system("broadwell").P
     waits = sorted(s["rows"] for s in by["events.wait"])
