@@ -98,6 +98,10 @@ _MAX_ELEMS = 1 << 22
 #: the precompute's tile: lanes (the f32 sublane tile) x chunk segment
 _TILE_ROWS = 8
 _TILE_SEG = 512     # the event kernel's segment (kernels/event_loop.py)
+#: schedule length from which a tile is _TILE_ROWS x _TILE_SEG consecutive
+#: chunks of one lane: such dispatches hold few real lanes, which a block of
+#: _TILE_ROWS lanes would pad out
+_LANE_MAJOR_K = 16384
 
 #: env var naming the default sequential event core
 EVENT_CORE_ENV = "REPRO_EVENT_CORE"
@@ -130,7 +134,10 @@ def _pow2_rows(n: int) -> int:
 
 def _tile_shape(B: int, K: int) -> Tuple[int, int]:
     """(rows, seg) of one precompute tile of a (B, K) lane block: both
-    divide the block's sides."""
+    divide the block's sides.  One lane's 4096 consecutive chunks from
+    ``_LANE_MAJOR_K`` on, else 8 lanes x 512 chunks."""
+    if K >= _LANE_MAJOR_K:
+        return 1, _TILE_ROWS * _TILE_SEG
     return math.gcd(B, _TILE_ROWS), math.gcd(K, _TILE_SEG)
 
 
@@ -260,7 +267,10 @@ def _effective_costs(grids, gs, grid_id, starts, sizes, loc, noise, count):
     Only the (rows x seg) tiles that some lane of their row block reaches
     (``count``) are computed, one tile per loop step; every other slot
     holds no chunk (starts = sizes = loc = 0), where the formula gives
-    0.0, so the result is the dense one bit for bit.
+    0.0, so the result is the dense one bit for bit.  A tile of one lane is
+    cut from the operands viewed as (B * K / 512, 512), as an (8, 512)
+    block: its consecutive chunks then fill whole (8, 128) vregs, not one
+    sublane in eight.
 
     The operations go under the scope ``precompute``, the loop itself does
     not: a device trace shows a while loop as one operation around those
@@ -268,17 +278,22 @@ def _effective_costs(grids, gs, grid_id, starts, sizes, loc, noise, count):
     B, K = starts.shape
     G = grids.shape[1] - 1
     rows, seg = _tile_shape(B, K)
+    w = _TILE_SEG if rows == 1 else K
+    shape = (seg // w, w) if rows == 1 else (rows, seg)
     with jax.named_scope("precompute"):
         n = (count.reshape(-1, rows).max(axis=1) + seg - 1) // seg
         ends = jnp.cumsum(n)
-        tiles, zeros = ends[-1], jnp.zeros((B, K), jnp.float32)
+        tiles, zeros = ends[-1], jnp.zeros((B * K // w, w), jnp.float32)
+        starts, sizes, loc, noise = (x.reshape(B * K // w, w)
+                                     for x in (starts, sizes, loc, noise))
 
     def tile(t, eff):
         with jax.named_scope("precompute"):
             blk = jnp.sum(ends <= t).astype(jnp.int32)
             r0 = blk * rows
             c0 = (t - ends[blk] + n[blk]) * seg
-            cut = lambda x: lax.dynamic_slice(x, (r0, c0), (rows, seg))
+            at = (r0 * (K // w) + c0 // w, c0 % w)
+            cut = lambda x: lax.dynamic_slice(x, at, shape)
             gid = lax.dynamic_slice(grid_id, (r0,), (rows,))[:, None]
             g = lax.dynamic_slice(gs, (r0,), (rows,))[:, None]
 
@@ -290,9 +305,11 @@ def _effective_costs(grids, gs, grid_id, starts, sizes, loc, noise, count):
 
             st, sz = cut(starts), cut(sizes)
             out = (pref(st + sz) - pref(st)) * cut(loc) * cut(noise)
-            return lax.dynamic_update_slice(eff, out, (r0, c0))
+            return lax.dynamic_update_slice(eff, out, at)
 
-    return lax.fori_loop(0, tiles, tile, zeros)
+    eff = lax.fori_loop(0, tiles, tile, zeros)
+    with jax.named_scope("precompute"):
+        return eff.reshape(B, K)
 
 
 def _batched_events_impl(P: int, core: str, grids, grid_id, inv_n, starts,
@@ -723,7 +740,9 @@ class JaxBatchedBackend(SimBackend):
                     lens = counts[sub]
                     with span("events.pack", K=K, rows=Bp, real=n,
                               chunks=int(lens.sum()),
-                              live=_live_slots(lens, Bp, K, self._shards)):
+                              live=_live_slots(lens, Bp, K, self._shards),
+                              tile_rows=_tile_shape(Bp // self._shards,
+                                                    K)[0]):
                         lanes = pack(sub, lens, Bp, K)
                     yield sub, lanes
 

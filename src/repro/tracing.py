@@ -33,8 +33,9 @@ SPANS = {
     "events.rows": ("sched_hits", "sched_misses", "steal_hits",
                     "steal_misses"),
     "sched.build": ("kind",),           # 0 central, 1 steal, 2 weighted
-    # live: the slots of the precompute's tiles that some chunk reaches
-    "events.pack": ("K", "rows", "real", "chunks", "live"),
+    # live: the slots of the precompute's tiles that some chunk reaches;
+    # tile_rows: the lanes of one tile (8, or 1 for long schedules)
+    "events.pack": ("K", "rows", "real", "chunks", "live", "tile_rows"),
     # with the grid stack's shape, the program's shapes
     "events.dispatch": ("P", "K", "rows", "grid_rows", "grid_cols"),
     "events.wait": ("rows",),

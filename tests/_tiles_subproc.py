@@ -24,10 +24,13 @@ def main() -> None:
     mesh = campaign_mesh(2)
     assert mesh.shape["data"] == 2, mesh.shape
     # 32 lanes: 16 a shard, two row blocks each; 8 lanes: 4 a shard, so a
-    # tile of 4 lanes
+    # tile of 4 lanes below K = 16384 and of one lane from there
     for P, core, B, K, counts in (
             (56, "while_loop", 32, 1024, _mixed(1024, 28)),
             (20, "while_loop", 8, 4096, np.array([0, 0, 17, 0, 4095, 3])),
+            # lane-major tiles, each shard's lanes on their own
+            (56, "while_loop", 8, 16384, np.array([0, 9000, 0, 0, 4097, 1])),
+            (128, "while_loop", 8, 262144, np.array([0, 0, 0, 0, 0, 65538])),
             (20, "pallas", 16, 256, _mixed(256, 10))):
         fn = _sharded_events(mesh, P, core)
         check(P, core, lanes(B, K, P, counts=counts, steal=True), events=fn)

@@ -84,9 +84,11 @@ def lanes(B, K, P, counts, seed=0, steal=False, perturbed=False):
     forced = np.full((B, K), -1, np.int32)
     N = np.ones(B, np.int64)
     for b, c in enumerate(counts):
+        c = int(c)
         if c == 0:
             continue
-        N[b] = int(rng.integers(max(c, 2 * c), 30518 * c + 1))
+        # stream's 2e9 iterations in 65536 chunks, within int32 starts
+        N[b] = int(rng.integers(2 * c, min(30518 * c, 2**31 - 1) + 1))
         cuts = np.sort(rng.choice(np.arange(1, N[b]), c - 1, replace=False))
         bounds = np.concatenate([[0], cuts, [N[b]]])
         starts[b, :c] = bounds[:-1]
@@ -117,10 +119,26 @@ def _mixed(K, n):
     return counts
 
 
+def _long(K, n, seed=0):
+    """``n`` real lanes of an 8-lane dispatch of K >= 16384, where a tile
+    is 4096 chunks of one lane: the first one slot short of a tile."""
+    counts = np.random.default_rng(K + n + seed).integers(1, K + 1, n)
+    counts[0] = 4095
+    return counts
+
+
 #: case -> (P, core, B, K, lanes' keywords)
 CASES = {
     # stream on epyc with expChunk: 65538 chunks spill into K = 262144
     "spill": (128, "while_loop", 8, 262144, dict(counts=[65538])),
+    # lane-major tiles (K >= 16384) with 1, 3 and 8 real lanes
+    **{f"lanes{n}_K{K}": (56, "while_loop", 8, K, dict(counts=_long(K, n)))
+       for K in (16384, 262144) for n in (1, 3, 8)},
+    # a StaticSteal row (forced PEs) past 65536 chunks, beside an SS row
+    "long_steal": (128, "while_loop", 8, 262144,
+                   dict(counts=[65664, 65538], steal=True)),
+    "long_perturbed": (20, "while_loop", 8, 16384,
+                       dict(counts=_long(16384, 3, 1), perturbed=True)),
     "full": (20, "while_loop", 16, 1024, dict(counts=[1024] * 16)),
     "mixed": (56, "while_loop", 32, 1024, dict(counts=_mixed(1024, 30))),
     "steal": (20, "while_loop", 16, 4096,
@@ -175,9 +193,13 @@ def test_tiled_precompute_is_bit_identical(case):
 
 
 def test_live_slots_count_the_tiles_a_dispatch_fills():
-    # 8 x 512 tiles: a 65538-chunk lane fills 129 of an (8, 262144) batch
-    assert jb._tile_shape(8, 262144) == (8, 512)
-    assert jb._live_slots(np.array([65538]), 8, 262144) == 129 * 8 * 512
+    # from K = 16384 a tile is 4096 chunks of one lane: a 65538-chunk lane
+    # fills 17 of an (8, 262144) batch
+    assert jb._tile_shape(8, 262144) == (1, 4096)
+    assert jb._live_slots(np.array([65538]), 8, 262144) == 17 * 4096
+    assert jb._tile_shape(8, 4096) == (8, 512)
+    assert jb._tile_shape(8, 16384) == (1, 4096)
+    assert jb._live_slots(np.array([1, 4096, 0, 4097]), 8, 16384) == 4 * 4096
     # full rows cover the batch; padding rows and blocks cover nothing
     assert jb._live_slots(np.full(16, 1024), 16, 1024) == 16 * 1024
     assert jb._live_slots(np.array([1, 0, 0]), 16, 1024) == 8 * 512
@@ -185,3 +207,5 @@ def test_live_slots_count_the_tiles_a_dispatch_fills():
     # each of 2 shards of 4 lanes is tiled on its own
     assert jb._tile_shape(4, 256) == (4, 256)
     assert jb._live_slots(np.array([0, 0, 0, 0, 3]), 8, 256, 2) == 4 * 256
+    assert jb._live_slots(np.array([0, 0, 0, 0, 5000]), 8, 65536, 2) == (
+        2 * 4096)

@@ -13,7 +13,7 @@ from jax.profiler import ProfileData
 
 from repro.core import SimPolicy
 from repro.sim import LoopWhatIf, get_application, get_system
-from repro.sim.backends.jax_batched import JaxBatchedBackend
+from repro.sim.backends.jax_batched import JaxBatchedBackend, _tile_shape
 from repro.sim.campaign import CellSpec, ReplayBatch
 from repro.tracing import SPANS
 
@@ -118,6 +118,10 @@ def test_stats_are_consistent(traced):
         assert s["real"] <= s["chunks"] <= s["real"] * s["K"]
         # the precompute's live tiles hold every chunk, within the batch
         assert s["chunks"] <= s["live"] <= s["rows"] * s["K"]
+        # whole tiles of the shape the program cuts for (rows, K)
+        rows, seg = _tile_shape(s["rows"], s["K"])
+        assert s["tile_rows"] == rows == (1 if s["K"] >= 16384 else 8)
+        assert s["live"] % (rows * seg) == 0
     for s in by["events.dispatch"]:
         assert s["P"] == get_system("broadwell").P
     waits = sorted(s["rows"] for s in by["events.wait"])
