@@ -10,7 +10,6 @@ Benches:
     degradation  Fig. 5      selector degradation vs Oracle
     traces       Figs. 7 & 8 per-instance selection traces
     serving      L3          chunk-scheduled dispatch vs selectors
-    autotune     L2          step-plan selection on a real model
     backends     §Backends   portfolio sweep: python vs batched JAX engine
     replay       §Backends   lockstep multi-cell replay vs sequential
     event_kernel §Backends   while_loop vs Pallas event core
@@ -154,11 +153,10 @@ def main() -> None:
         run_gate(args._gate, args.tier)
         return
 
-    from . import (bench_anova, bench_autotune, bench_backends, bench_chunks,
-                   bench_cov, bench_degradation, bench_event_kernel,
-                   bench_faults, bench_fleet, bench_learned, bench_perturb,
-                   bench_replay, bench_serving, bench_shard, bench_simpolicy,
-                   bench_traces)
+    from . import (bench_anova, bench_backends, bench_chunks, bench_cov,
+                   bench_degradation, bench_event_kernel, bench_faults,
+                   bench_fleet, bench_learned, bench_perturb, bench_replay,
+                   bench_serving, bench_shard, bench_simpolicy, bench_traces)
     benches = {
         "chunks": bench_chunks.main,
         "cov": bench_cov.main,
@@ -166,7 +164,6 @@ def main() -> None:
         "anova": bench_anova.main,
         "traces": bench_traces.main,
         "serving": bench_serving.main,
-        "autotune": bench_autotune.main,
         "backends": bench_backends.main,
         "replay": bench_replay.main,
         "event_kernel": bench_event_kernel.main,

@@ -1,71 +1,35 @@
-"""End-to-end serving driver (the paper's kind of system: a runtime that
-selects scheduling algorithms online).
+"""Chunk-self-scheduled serving dispatch (the paper's kind of system: a
+runtime that selects scheduling algorithms online).
 
-Part 1 — LIVE: a reduced llama-family model decodes real tokens under
-continuous batching (jitted serve_step, KV cache, slot refill).
-
-Part 2 — SCALE: 16 replica groups serve a heavy-tailed request stream;
-the dispatcher self-schedules request chunks with the 12-algorithm portfolio
-and each selection method picks the algorithm online (LT/LIB from measured
-wave times).  Compare against the fixed-algorithm baselines.
+16 replica groups serve a heavy-tailed request stream; the dispatcher
+self-schedules request chunks with the 12-algorithm portfolio and each
+selection method picks the algorithm online (LT/LIB from measured wave
+times).  Compare against the fixed-algorithm baselines.
 
     PYTHONPATH=src python examples/serve.py [--requests 4096]
 """
 
 import argparse
-import dataclasses
 
-import jax
-import jax.numpy as jnp
-
-from repro.configs import get_config, smoke_reduce
 from repro.core import ALGORITHM_NAMES
 from repro.data import synthetic_requests
-from repro.models import decode_step, init_decode_cache, init_params
-from repro.serving import (ContinuousBatcher, DispatchSimulator,
-                           ReplicaCostModel)
+from repro.serving import DispatchSimulator
 
 
-def live_part():
-    print("== live continuous batching (reduced llama3.2 family) ==")
-    cfg = dataclasses.replace(smoke_reduce(get_config("llama3.2-3b")),
-                              n_layers=2, vocab_size=256)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    SLOTS, MAXLEN = 8, 256
-    cache = init_decode_cache(cfg, SLOTS, MAXLEN)
-    serve = jax.jit(lambda p, c, t: decode_step(cfg, p, c, t))
-
-    reqs = synthetic_requests(32, seed=0, mean_prompt=8, mean_gen=24)
-    batcher = ContinuousBatcher(serve, None, SLOTS)
-    batcher.submit(reqs)
-    toks = jnp.zeros((SLOTS,), jnp.int32)
-    stats = batcher.run(params, cache, toks, max_steps=220)
-    print(f"  decoded {stats['tokens']} tokens in {stats['wall']:.2f}s "
-          f"({stats['tokens_per_s']:.0f} tok/s), "
-          f"completed {stats['completed']}/32 requests")
-    # calibrate the replica cost model from the measured step
-    per_tok = stats["wall"] / max(stats["tokens"], 1)
-    print(f"  calibrated per-token cost: {per_tok * 1e6:.0f} us")
-    return per_tok
-
-
-def scale_part(n_requests: int, per_tok: float):
-    print("\n== chunk-self-scheduled dispatch over 16 replica groups ==")
+def dispatch_study(n_requests: int):
+    print("== chunk-self-scheduled dispatch over 16 replica groups ==")
     reqs = synthetic_requests(n_requests, seed=7, heavy_tail=1.15)
-    cost = ReplicaCostModel(per_token=per_tok / 50)  # replica group >> 1 dev
     rows = []
     for alg in (0, 1, 2, 6):
         sim = DispatchSimulator(16, selector="Fixed",
-                                selector_kw={"algorithm": alg},
-                                cost_model=cost)
+                                selector_kw={"algorithm": alg})
         sim.run(reqs)
         s = sim.summary()
         rows.append((f"fixed {ALGORITHM_NAMES[alg]}", s))
     for sel, reward in [("ExhaustiveSel", None), ("QLearn", "LT"),
                         ("QLearn", "LIB"), ("SARSA", "LT"),
                         ("Hybrid", "LT"), ("Hybrid", "p95")]:
-        sim = DispatchSimulator(16, selector=sel, reward=reward or "LT",
-                                cost_model=cost)
+        sim = DispatchSimulator(16, selector=sel, reward=reward or "LT")
         sim.run(reqs)
         tag = f"{sel}+{reward}" if reward else sel
         rows.append((tag, sim.summary()))
@@ -80,8 +44,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=4096)
     args = ap.parse_args()
-    per_tok = live_part()
-    scale_part(args.requests, per_tok)
+    dispatch_study(args.requests)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,8 @@
 """Scheduling-algorithm selection policies (paper §3.2-3.5, §6).
 
 Every method implements the structured :class:`repro.core.api.SelectionPolicy`
-protocol so the simulator, serving dispatcher and step-plan autotuner can
-drive any of them through one surface:
+protocol so the simulator and the serving dispatcher can drive any of
+them through one surface:
 
     policy = make_policy("QLearn", reward="LT", seed=0)
     for t in range(T):
@@ -477,7 +477,7 @@ def make_policy(name: str, **kw) -> SelectionPolicy:
         if "simulator" not in kw:
             raise ValueError(
                 f"policy {name!r} needs a simulator= candidate pricer "
-                f"(LoopWhatIf / WaveWhatIf / PlanWhatIf)")
+                f"(LoopWhatIf / WaveWhatIf)")
         if canon in ("SimPolicy", "ReactiveSim", "AwareSim"):
             # AwareSim is a plain SimPolicy; its two-pass adaptive-surrogate
             # pricing lives in the lane's LoopWhatIf (campaign wiring keys on

@@ -2,8 +2,8 @@
 
 LB4OMP assigns a unique id to every ``schedule(runtime)`` loop so that each
 loop learns independently.  ``SelectionService`` mirrors that — callers
-register a region id (an OpenMP loop in the simulator, a jitted step in the
-autotuner, a dispatch queue in serving) and get an isolated
+register a region id (an OpenMP loop in the simulator, a dispatch queue in
+serving) and get an isolated
 :class:`~repro.core.api.SelectionPolicy` — and adds the two paper
 extensions the old begin/end registry could not reach:
 

@@ -30,16 +30,15 @@ A *candidate simulator* is anything with::
     price(cands) -> Sequence[Observation] | array of predicted loop times
 
 Concrete simulators live next to their execution layers:
-``repro.sim.whatif.LoopWhatIf`` (DES loop instances),
+``repro.sim.whatif.LoopWhatIf`` (DES loop instances) and
 ``repro.serving.engine.WaveWhatIf`` (dispatch waves via
-``DispatchSimulator.what_if``), and
-``repro.distributed.autotune.PlanWhatIf`` (calibrated step-plan cost model).
+``DispatchSimulator.what_if``).
 A simulator that cannot price yet (no context bound) raises
 :class:`SimUnavailable`; the policies degrade to their live fallbacks.
 
 ``REPRO_SIM_POLICY`` names the sim-assisted method consumers should default
-to (e.g. ``SimPolicy`` / ``SimHybrid``): ``SelectionService``,
-``DispatchSimulator`` and ``StepAutoTuner`` resolve it when no explicit
+to (e.g. ``SimPolicy`` / ``SimHybrid``): ``SelectionService``
+and ``DispatchSimulator`` resolve it when no explicit
 method is given, so a whole campaign can be flipped to simulation-assisted
 selection from the environment.
 """

@@ -1,2 +1,2 @@
-from .pipeline import (DataConfig, TokenPipeline, Request, field_rng,
-                       request_lengths, synthetic_requests)
+from .pipeline import (Request, field_rng, request_lengths,
+                       synthetic_requests)

@@ -1,8 +1,8 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrapper around the Pallas event kernel.
 
-On CPU the kernels execute in interpret mode — the kernel body runs in
-Python with real block indexing, which is what the per-kernel allclose tests
-validate.  On TPU backends the same calls compile via Mosaic
+On CPU the kernel executes in interpret mode — the kernel body runs in
+Python with real block indexing, which is what ``tests/test_event_kernel.py``
+validates.  On TPU backends the same call compiles via Mosaic
 (``tests/test_chip_compile.py`` compiles the event kernel for a v5e).
 """
 
@@ -10,10 +10,7 @@ from __future__ import annotations
 
 import jax
 
-from .event_loop import event_finish as _event_finish
-from .flash_attention import flash_attention as _flash
-from .rmsnorm import rmsnorm as _rmsnorm
-from .ssd_scan import ssd_scan as _ssd
+from .event_loop import DEFAULT_SEG, event_finish as _event_finish
 
 
 def _interpret() -> bool:
@@ -32,24 +29,8 @@ def preferred_event_core() -> str:
     return "while_loop" if _interpret() else "pallas"
 
 
-def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 256,
-                    block_kv: int = 512):
-    return _flash(q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
-                  interpret=_interpret())
-
-
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, head_block: int = 8):
-    return _ssd(x, dt, A, B, C, chunk=chunk, head_block=head_block,
-                interpret=_interpret())
-
-
-def rmsnorm(x, w, *, eps: float = 1e-5, block_rows: int = 256):
-    return _rmsnorm(x, w, eps=eps, block_rows=block_rows,
-                    interpret=_interpret())
-
-
 def event_finish(eff, speed, jitter, h_eff, bcost, forced, count, *,
-                 seg: int = 512):
+                 seg: int = DEFAULT_SEG):
     return _event_finish(eff, speed, jitter, h_eff, bcost, forced, count,
                          seg=seg, interpret=_interpret())
 

@@ -1,1 +1,1 @@
-from .mesh import make_production_mesh, make_host_mesh
+from .mesh import campaign_mesh, make_host_mesh

@@ -1,8 +1,9 @@
-"""Production mesh construction.
+"""Host meshes and the campaign's lane-sharding rule.
 
-A FUNCTION (not a module-level constant) so importing this module never
-touches jax device state — required for the smoke tests, which must see one
-CPU device while the dry-run subprocess sees 512 placeholder devices.
+Meshes are built by FUNCTIONS (not module-level constants) so importing
+this module never touches jax device state.  The data axis, the lane spec
+and the lane padding live here together: every batched lane dimension
+shards over ``data`` and nothing else.
 """
 
 from __future__ import annotations
@@ -11,13 +12,7 @@ from typing import Optional
 
 import jax
 import numpy as np
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; (2, 16, 16) = 512 chips across two pods."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def make_host_mesh(model_parallel: int = 1,
@@ -56,3 +51,25 @@ def campaign_mesh(data_parallel: Optional[int] = None):
     rows) shards over ``data``; ``model`` stays 1 — the event cores are
     per-lane sequential and never split a lane across devices."""
     return make_host_mesh(model_parallel=1, data_parallel=data_parallel)
+
+
+def lane_spec(mesh: Mesh) -> P:
+    """Leading-axis lane sharding for campaign batches: instances / what-if
+    candidate rows shard over ``data``, everything trailing (schedule
+    slots, PEs) stays local to the lane's device."""
+    return P("data")
+
+
+def lane_count(mesh: Mesh) -> int:
+    """Extent of the ``data`` axis — the number of lane shards."""
+    return mesh.shape["data"]
+
+
+def pad_lanes(n: int, mesh: Mesh) -> int:
+    """Round a lane count up to a multiple of the mesh's data extent so the
+    leading axis divides evenly under ``shard_map``.  Padding lanes carry
+    ``count == 0`` schedules (the event cores never execute them) and are
+    sliced off host-side — bit-equality to the unsharded path is preserved
+    by construction."""
+    d = lane_count(mesh)
+    return -(-n // d) * d

@@ -1,6 +1,6 @@
-from .trainer import Trainer, TrainerConfig, SimulatedFailure
 from .policy_trainer import (PolicyTrainer, PolicyTrainerConfig,
-                             TransitionDataset, train_policy_state)
+                             SimulatedFailure, TransitionDataset,
+                             train_policy_state)
 
-__all__ = ["Trainer", "TrainerConfig", "SimulatedFailure", "PolicyTrainer",
-           "PolicyTrainerConfig", "TransitionDataset", "train_policy_state"]
+__all__ = ["SimulatedFailure", "PolicyTrainer", "PolicyTrainerConfig",
+           "TransitionDataset", "train_policy_state"]

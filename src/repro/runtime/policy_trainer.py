@@ -1,11 +1,9 @@
 """Offline trainer for the learned selection policy.
 
-This is the first real consumer of the training stack the repo has carried
-dormant since the runtime PRs: the net is the ``models/layers.py`` MLP
-block (``gelu_mlp``) behind one feature layer, the optimizer is
-``optim/adamw.py`` (schedules, global-norm clipping), and the run
-discipline is ``runtime/trainer.py``'s checkpoint/restart contract —
-atomic sharded saves through :class:`~repro.checkpoint.manager.
+The net is one GELU MLP block (``gelu_mlp``) behind one feature layer, the
+optimizer is ``optim/adamw.py`` (schedules, global-norm clipping), and the
+run discipline is a checkpoint/restart contract — atomic sharded saves
+through :class:`~repro.checkpoint.manager.
 CheckpointManager`, async checkpointing off the critical path, SIGTERM →
 final synchronous save, ``failure_rate`` fault injection with
 restore-and-replay, and **bit-identical resume** (test-enforced): batches
@@ -36,12 +34,19 @@ import numpy as np
 
 from ..checkpoint.manager import CheckpointManager
 from ..core.learned import N_FEATURES, make_learned_state
-from ..models.layers import gelu_mlp
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
-from .trainer import SimulatedFailure
 
 __all__ = ["TransitionDataset", "PolicyTrainerConfig", "PolicyTrainer",
-           "forward", "train_policy_state"]
+           "SimulatedFailure", "forward", "train_policy_state"]
+
+
+class SimulatedFailure(RuntimeError):
+    """An injected node failure (``failure_rate``) at a step boundary."""
+
+
+def gelu_mlp(x, w1, b1, w2, b2):
+    h = jax.nn.gelu(jnp.einsum("...d,df->...f", x, w1) + b1)
+    return jnp.einsum("...f,fd->...d", h, w2) + b2
 
 
 def forward(params: Dict[str, jnp.ndarray], x: jnp.ndarray) -> jnp.ndarray:
@@ -62,8 +67,7 @@ class TransitionDataset:
     no bearing on the argmin; centering removes it so the net spends
     capacity on *ranking* algorithms, not predicting absolute runtimes).
 
-    ``batch_at(step)`` is a pure function of ``(seed, step)`` — the
-    :class:`~repro.data.pipeline.TokenPipeline` resume contract — which is
+    ``batch_at(step)`` is a pure function of ``(seed, step)``, which is
     what makes checkpoint-restored training bit-identical.
     """
 
@@ -149,9 +153,9 @@ class PolicyTrainerConfig:
 
 
 class PolicyTrainer:
-    """Supervised contextual-bandit training with the Trainer's
-    fault-tolerance discipline (checkpoint/restart, SIGTERM final save,
-    injected failures, bit-identical resume)."""
+    """Supervised contextual-bandit training with a fault-tolerance
+    discipline (checkpoint/restart, SIGTERM final save, injected failures,
+    bit-identical resume)."""
 
     def __init__(self, dataset: TransitionDataset, cfg: PolicyTrainerConfig,
                  opt_cfg: Optional[AdamWConfig] = None):

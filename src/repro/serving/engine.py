@@ -13,8 +13,8 @@ Structure of the adaptation (DESIGN.md §2):
 
 ``DispatchSimulator`` runs waves through the DES engine (replica service
 time = token-count cost model measured from a real decode step or supplied
-analytically).  ``ContinuousBatcher`` is the live path: real jitted decode
-on slots, used by examples/serve driver.
+analytically).  ``ContinuousBatcher`` is the live path: a jitted decode
+step on slots.
 """
 
 from __future__ import annotations
@@ -309,8 +309,8 @@ class DispatchSimulator:
 
 
 class ContinuousBatcher:
-    """Live continuous batching over a real jitted decode step (single
-    replica group; the examples drive this with a reduced model)."""
+    """Live continuous batching over a jitted decode step (single replica
+    group)."""
 
     def __init__(self, serve_step, init_cache_fn, batch_slots: int,
                  eos_check: Optional[Callable] = None):

@@ -33,9 +33,9 @@ rep x time-step) — at once:
 4.  On a multi-device host every batched lane dimension — ``run_batch`` /
     ``run_lockstep`` instances and the serving what-if candidate rows —
     executes under ``jax.shard_map`` over the campaign mesh's ``data``
-    axis (``launch.mesh.campaign_mesh`` + ``distributed.sharding`` lane
-    specs): lanes are embarrassingly parallel, so each device runs the
-    identical per-lane computation on its shard and the results are
+    axis (``launch.mesh``: ``campaign_mesh`` and its lane specs): lanes
+    are embarrassingly parallel, so each device runs the identical per-lane
+    computation on its shard and the results are
     bit-identical to the single-device path (lane counts are padded to the
     mesh extent and masked with ``count == 0``; ``tests/test_shard.py``).
     ``data_parallel=`` / ``REPRO_DATA_PARALLEL`` clamp the mesh.  The host
@@ -79,8 +79,8 @@ from jax.sharding import PartitionSpec as Pspec
 from ...core.jaxsched import (chunk_schedule, staticsteal_schedule,
                               weighted_adaptive_schedule)
 from ...core.portfolio import ADAPTIVE_SET
-from ...distributed.sharding import lane_count, lane_spec, pad_lanes
-from ...launch.mesh import campaign_mesh
+from ...kernels.event_loop import DEFAULT_SEG as _TILE_SEG
+from ...launch.mesh import campaign_mesh, lane_count, lane_spec, pad_lanes
 from ...tracing import span
 from ..workloads import profile_digest as _profile_digest
 from ..workloads import stack_prefix_grids
@@ -97,7 +97,6 @@ _K_BUCKETS = (256, 1024, 4096, 16384, 65536, 262144)
 _MAX_ELEMS = 1 << 22
 #: the precompute's tile: lanes (the f32 sublane tile) x chunk segment
 _TILE_ROWS = 8
-_TILE_SEG = 512     # the event kernel's segment (kernels/event_loop.py)
 #: schedule length from which a tile is _TILE_ROWS x _TILE_SEG consecutive
 #: chunks of one lane: such dispatches hold few real lanes, which a block of
 #: _TILE_ROWS lanes would pad out

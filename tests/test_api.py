@@ -499,7 +499,7 @@ def test_hybrid_warmstart_roundtrip(tmp_path):
 
 def test_decide_is_a_pure_peek_for_every_policy():
     """Repeated decide() without feedback must not change the selection or
-    advance any RNG (callers like StepAutoTuner.selected_plan peek)."""
+    advance any RNG (callers may peek at the selection)."""
     for name in ("RandomSel", "ExhaustiveSel", "ExpertSel", "QLearn",
                  "SARSA", "Hybrid"):
         p = make_policy(name, seed=11)

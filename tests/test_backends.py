@@ -10,6 +10,7 @@ import pytest
 from repro.core.jaxsched import chunk_schedule, staticsteal_schedule
 from repro.sim import (InstanceSpec, LoopProfile, backend_names, get_backend,
                        get_system, sweep_portfolio)
+from repro.sim.whatif import noise_free
 
 # P a power of two and unit an exact binary fraction keep the adaptive
 # algorithms' telemetry bit-exact (variance exactly 0, weights exactly 1),
@@ -54,17 +55,26 @@ def test_event_cap_is_shared():
 # noise-free exact equivalence, every portfolio algorithm
 # ---------------------------------------------------------------------------
 
+#: QUIET, then the noise-free twins of the paper's machines (P = 20, 56,
+#: 128): chunk sequences depend on P
+NOISE_FREE_SYSTEMS = {"quiet": QUIET} | {
+    name: noise_free(get_system(name))
+    for name in ("broadwell", "cascadelake", "epyc")}
+
+
+@pytest.mark.parametrize("system", list(NOISE_FREE_SYSTEMS))
 @pytest.mark.parametrize("alg", range(12))
 @pytest.mark.parametrize("cp", [0, 37])
-def test_noise_free_chunk_sequences_and_makespan(alg, cp):
+def test_noise_free_chunk_sequences_and_makespan(alg, cp, system):
     py = get_backend("python")
     jx = get_backend("jax")
-    rp = py.run_instance(UNIFORM, QUIET, alg, cp, np.random.default_rng(0),
+    sysm = NOISE_FREE_SYSTEMS[system]
+    rp = py.run_instance(UNIFORM, sysm, alg, cp, np.random.default_rng(0),
                          record_chunks=True)
-    rj = jx.run_instance(UNIFORM, QUIET, alg, cp, np.random.default_rng(0),
+    rj = jx.run_instance(UNIFORM, sysm, alg, cp, np.random.default_rng(0),
                          record_chunks=True)
     assert rp.n_chunks == rj.n_chunks
-    assert rp.chunk_sizes == rj.chunk_sizes, f"alg {alg} cp {cp}"
+    assert rp.chunk_sizes == rj.chunk_sizes, f"alg {alg} cp {cp} {system}"
     np.testing.assert_allclose(rj.loop_time, rp.loop_time, rtol=1e-4)
     np.testing.assert_allclose(rj.lib, rp.lib, atol=0.05)
 

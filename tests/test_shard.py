@@ -21,7 +21,8 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.launch.mesh import campaign_mesh, make_host_mesh
+from repro.launch.mesh import (campaign_mesh, lane_count, lane_spec,
+                               make_host_mesh, pad_lanes)
 from repro.sim import CellSpec, ReplayBatch, sweep_portfolio
 from repro.sim.backends.jax_batched import (JaxBatchedBackend,
                                             resolve_async_dispatch,
@@ -60,8 +61,6 @@ def test_campaign_mesh_is_data_only():
 
 
 def test_lane_padding_helpers():
-    from repro.distributed.sharding import lane_count, lane_spec, pad_lanes
-
     m = campaign_mesh(data_parallel=1)
     assert lane_count(m) == 1
     assert pad_lanes(7, m) == 7

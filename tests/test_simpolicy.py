@@ -4,7 +4,7 @@ The core contract: on a noise-free cell the policy's argmin over the priced
 candidate set must equal the Oracle selector's choice — on BOTH simulation
 backends — and the sim-pruned hybrid's exploration set must always be a
 subset of the full portfolio containing the Oracle pick.  Wiring tests cover
-the three execution layers (campaign lanes, dispatch waves, step plans) and
+the two execution layers (campaign lanes, dispatch waves) and
 the ``REPRO_SIM_POLICY`` environment selection.
 """
 
@@ -99,10 +99,29 @@ def test_simpolicy_argmin_matches_oracle_noise_free(backend):
     assert (spread[1] - spread[0]) / spread[0] > 0.2
 
 
-def test_simpolicy_backends_agree():
-    """Both engines elect the same candidate on the well-separated cell."""
-    profile = get_application("tc").loops(0)[0]
-    system = get_system("epyc")
+#: the JAX backend's StaticSteal schedule is fixed before the run, so on a
+#: non-uniform loop it cannot steal where the load sits: sphynx L0 at t=250
+#: on broadwell, chunk 781, prices at 2.349 s with LIB 57% against the
+#: python reference's 1.060 s and 0.7%, and SimPolicy elects AutoLLVM
+_STATICSTEAL_NONUNIFORM = pytest.mark.xfail(
+    strict=True, reason="JAX StaticSteal schedule mis-prices a non-uniform "
+    "loop (sphynx L0 t=250 broadwell): 2.349 s vs 1.060 s")
+
+#: the well-separated tc/epyc cell, then the consult cell's 9 (loop,
+#: machine) pairs (bench/traffic/consult_varying_loops.json) mid-run
+_AGREE_CELLS = [("tc", 0, 0, "epyc")] + [
+    pytest.param(app, loop, 250, system,
+                 marks=(_STATICSTEAL_NONUNIFORM
+                        if (app, system) == ("sphynx", "broadwell") else ()))
+    for app, loop in (("mandelbrot", 1), ("mandelbrot", 2), ("sphynx", 0))
+    for system in ("broadwell", "cascadelake", "epyc")]
+
+
+@pytest.mark.parametrize("app,loop,t,system_name", _AGREE_CELLS)
+def test_simpolicy_backends_agree(app, loop, t, system_name):
+    """Both engines elect the same candidate for the same loop context."""
+    profile = get_application(app).loops(t)[loop]
+    system = get_system(system_name)
     picks = []
     for backend in BACKENDS:
         whatif = LoopWhatIf(system, backend=backend)
@@ -259,7 +278,7 @@ def test_sim_lane_decisions_follow_the_sim_winner():
 
 
 # ---------------------------------------------------------------------------
-# serving + autotuner wiring
+# serving wiring
 # ---------------------------------------------------------------------------
 
 def _requests(n=192, seed=0):
@@ -323,41 +342,6 @@ def test_wave_whatif_candidates_cover_chunk_variants():
     priced = wi.price(cands)
     assert len(priced) == 24
     assert all(o.loop_time > 0 for o in priced)
-
-
-def test_step_autotuner_simpolicy_compiles_only_the_winner():
-    from repro.distributed.autotune import (DEFAULT_PLANS, PlanWhatIf,
-                                            StepAutoTuner)
-
-    built = []
-
-    def build(plan):
-        built.append(plan.name)
-        return lambda *a: np.zeros(1)
-
-    tuner = StepAutoTuner(list(DEFAULT_PLANS), build, method="SimPolicy")
-    assert isinstance(tuner.sim_model, PlanWhatIf)
-    for _ in range(4):
-        tuner.step()
-    # retuning epochs priced the portfolio in simulation: only the predicted
-    # winner was ever compiled or executed live
-    assert built == ["mb1_noremat"]
-    assert tuner.sim_model._scale is not None
-
-
-def test_plan_whatif_prior_ordering_and_calibration():
-    from repro.distributed.autotune import DEFAULT_PLANS, PlanWhatIf
-
-    m = PlanWhatIf(list(DEFAULT_PLANS))
-    priors = {p.name: m.prior(p) for p in DEFAULT_PLANS}
-    assert priors["mb1_noremat"] < priors["mb1_remat"]    # remat costs FLOPs
-    assert priors["mb1_remat"] < priors["mb4_remat"]      # mb overhead
-    m.observe(3, 2.0)                    # mb1_noremat measured at 2 s
-    priced = m.price(m.candidates())
-    assert priced[3].loop_time == pytest.approx(2.0)
-    # unobserved plans scale by the calibrated seconds-per-unit
-    assert priced[0].loop_time == pytest.approx(
-        2.0 / m.prior(DEFAULT_PLANS[3]) * m.prior(DEFAULT_PLANS[0]))
 
 
 # ---------------------------------------------------------------------------
